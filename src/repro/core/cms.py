@@ -8,9 +8,10 @@ device sketches its own edge shard and one all-reduce merges the sketches
 Hashing: multiply-shift universal hashing in uint32 (wraps mod 2^32), then
 mod ``cols``. The paper uses 4 hash rows and cols ≈ 1e-4 × |E|.
 
-The hot update path has a Pallas TPU kernel (kernels/cms) that turns the
-scatter-add into a one-hot × matmul on the MXU; this module is the
-reference / small-scale path and the public API.
+The pipeline's sketch updates (``core/supergraph.community_sizes`` and
+``sharded_update`` below) go through ``kernels/cms``, a Pallas TPU kernel
+that turns the scatter-add into a one-hot × matmul on the MXU; this
+module's ``update`` is the plain scatter-add form and the public API.
 """
 from __future__ import annotations
 
@@ -90,8 +91,10 @@ def sharded_update(mesh, cfg: CMSConfig):
     axes = tuple(mesh.axis_names)
     row1d = P(row_chunk_spec(mesh)[0])  # 1-D operands: drop the trailing None
 
+    from repro.kernels.cms import ops as cms_ops
+
     def body(sketch, keys, weights):
-        local = update(jnp.zeros_like(sketch), keys, weights, cfg)
+        local = cms_ops.update(jnp.zeros_like(sketch), keys, weights, cfg)
         return sketch + jax.lax.psum(local, axes)
 
     mapped = shard_map_compat(

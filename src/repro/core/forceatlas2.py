@@ -274,6 +274,22 @@ def _attraction_sorted(pos, src, dst, w, n: int):
     )
 
 
+def _total_force(*terms):
+    """Sum per-node force terms (gravity, attraction, repulsion).
+
+    The barrier materializes each term before the adds. Without it XLA
+    fuses a term's last multiply into the sum where it likes, and a fused
+    multiply-add rounds once where a multiply then an add rounds twice:
+    the single-device and the sharded layout bodies, fused differently,
+    then drew totals 1 ulp apart. With it both add the same three arrays.
+    """
+    terms = jax.lax.optimization_barrier(terms)
+    total = terms[0]
+    for t in terms[1:]:
+        total = total + t
+    return total
+
+
 def _pair_force(dpos, mi, mj, kr):
     """kr·mi·mj/d along the unit vector, for a [..., 2] displacement."""
     d2 = jnp.sum(dpos * dpos, axis=-1)
@@ -490,9 +506,11 @@ def _layout_jit(edges, weights, mass, n: int, cfg: FA2Config, pos0):
             )
         elif grid_state:
             cell, order = grid_ops.bin_and_sort(pos, cfg.grid_size)
-        f = _gravity(pos, mass, cfg)
-        f = f + _attraction_sorted(pos, src, dst, w2, n)
-        f = f + _repulsion_forces(pos, mass, radii, cfg, cell=cell, order=order)
+        f = _total_force(
+            _gravity(pos, mass, cfg),
+            _attraction_sorted(pos, src, dst, w2, n),
+            _repulsion_forces(pos, mass, radii, cfg, cell=cell, order=order),
+        )
         core, row = _apply_speed_guarded(core, f, mass, cfg)
         return core, cell, order, row
 
@@ -538,18 +556,22 @@ def _layout_jit(edges, weights, mass, n: int, cfg: FA2Config, pos0):
 #   attraction  — full-size sorted segment-sum with non-owned sources
 #                 weight-masked, owned rows sliced: owned segments receive
 #                 exactly the single-device terms in the same order.
-#   exact rep.  — n ≤ 2048: replicated dense ref, rows sliced (the CPU auto
-#                 dispatch); n > 2048: ``repulsion_chunked_rows`` — the
-#                 j-chunk scan math on the owned rows only (rows are
-#                 independent, so bitwise equal at 1/D the work+memory).
+#   exact rep.  — ``repulsion_rows``: the owned rows through the same
+#                 backend one device would pick — the Pallas kernel on
+#                 TPU (target rows sliced, same blocks), on CPU the dense
+#                 ref sliced (n ≤ 2048) or the j-chunk scan on the owned
+#                 rows (rows are independent, so bitwise equal).
 #   grid rep.   — bin/sort/monopole stats replicated (O(n + G²)); far field
 #                 row-sliced through ``far_field_ref`` (per-node cell sums);
 #                 near field via the psum-free ``near_field_rows`` halo;
 #                 sorted rows gathered, then the unsort scatter replicated.
 #
 # Every cross-device step is a concatenation (all_gather) — never a float
-# reduction — so D-device layouts are bit-identical to the single-device
-# CPU dispatch ("exact"/"grid" backends; tests/test_sharded_pipeline.py).
+# reduction — and both bodies sum the force terms through ``_total_force``,
+# so D-device layouts are bit-identical to one device for "exact" on any
+# platform and for "grid" on CPU (tests/test_sharded_pipeline.py). On TPU
+# the single-device "grid" runs the Pallas grid kernels, which this body
+# does not mirror.
 # The adaptive stop composes with this for free: the gathered force array
 # (hence swing/traction, hence the converged flag) is replicated, so every
 # device freezes on the same iteration.
@@ -626,7 +648,7 @@ def _sharded_layout_fn(mesh, cfg: FA2Config, n: int):
             elif grid_state:
                 cell, order = grid_ops.bin_and_sort(pos, cfg.grid_size)
 
-            f_r = _gravity(rows(pos), rows(mass), cfg)
+            grav = _gravity(rows(pos), rows(mass), cfg)
 
             pos_ext = jnp.concatenate([pos, jnp.zeros((1, 2), pos.dtype)])
             own = (src >= i0) & (src < i0 + nl)
@@ -634,7 +656,6 @@ def _sharded_layout_fn(mesh, cfg: FA2Config, n: int):
             att = segment_ops.segment_sum(
                 fe, src, n, backend="ref", indices_are_sorted=True
             )
-            f_r = f_r + rows(att)
 
             if grid_state:
                 # This path only engages for cfg.dtype == "float32"
@@ -653,19 +674,13 @@ def _sharded_layout_fn(mesh, cfg: FA2Config, n: int):
                 )
                 force_s = jax.lax.all_gather(force_sr, axes, axis=0, tiled=True)
                 rep = jnp.zeros_like(force_s).at[order].set(force_s)
-                f_r = f_r + rows(rep.astype(pos.dtype))
+                rep_r = rows(rep.astype(pos.dtype))
             else:
-                r = radii if cfg.use_radii else None
-                if n <= 2048:
-                    f_r = f_r + rows(
-                        repulsion_ops.repulsion(pos, mass, kr, radii=r,
-                                                backend="ref")
-                    )
-                else:
-                    f_r = f_r + repulsion_ops.repulsion_chunked_rows(
-                        pos, mass, i0, nl, kr, radii=r,
-                        use_radii=cfg.use_radii,
-                    )
+                rep_r = repulsion_ops.repulsion_rows(
+                    pos, mass, i0, nl, kr,
+                    radii=radii if cfg.use_radii else None,
+                )
+            f_r = _total_force(grav, rows(att), rep_r)
 
             f = jax.lax.all_gather(f_r, axes, axis=0, tiled=True)
             core, row = _apply_speed_guarded(core, f, mass, cfg)
@@ -737,11 +752,11 @@ def layout_sharded(
     float32-pinned, so honoring ``cfg.dtype`` sharded is impossible; the
     single-device path keeps its cast-in/cast-out semantics). ``mesh=None``
     falls back silently — that is the caller opting out, not a surprise.
-    Bit-identical to the single-device *CPU* dispatch of "exact"/"grid"
-    (on TPU, ``layout``'s auto-dispatch picks Pallas kernels this path
-    does not mirror), including the adaptive stop: the converged flag is
-    computed from the replicated gathered forces, so the sharded run
-    freezes on exactly the same iteration.
+    Bit-identical to ``layout`` for "exact" on any platform and for
+    "grid" on CPU (on TPU, ``layout``'s "grid" runs Pallas grid kernels
+    this path does not mirror), including the adaptive stop: the
+    converged flag is computed from the replicated gathered forces, so
+    the sharded run freezes on exactly the same iteration.
     """
     if mesh is None:
         return layout(edges, weights, mass, n, cfg, pos0)

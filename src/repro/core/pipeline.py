@@ -257,8 +257,8 @@ def biggraphvis(
     tests/test_edge_store.py) and whatever the superedge-aggregation
     backend (``StreamConfig.agg_backend``: two-level "merge" default vs
     "lexsort" baseline). ``put`` is the host→device transfer for
-    chunk buffers (launch/stream_runner.py passes a sharded forced-copy
-    device_put; None selects the engine default for the source).
+    chunk buffers (launch/stream_runner.py passes a sharded
+    ``device_put_copied``; None selects the engine default for the source).
 
     ``render_path``/``render_cfg`` are deprecated shims (one
     ``DeprecationWarning`` per process) forwarding to the render entry

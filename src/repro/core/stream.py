@@ -8,7 +8,7 @@ every edge-consuming stage over fixed-size chunks:
 
     EdgeStore (host array · mmap .npy/.bin · sharded files)
         ──► EdgeChunkStream (padded chunk buffers)
-        ──► double-buffered host staging + forced-copy device_put
+        ──► double-buffered host staging + device_put_copied
         ──► per-chunk jitted update steps, state donated
             (SCoDA labels+degrees · graph degrees · superedge aggregation
              — two-level sorted-merge by default, ``StreamConfig.
@@ -20,10 +20,11 @@ independent of |E| — so edge lists larger than device memory process in
 ``rounds + 1`` passes. With a disk-backed ``EdgeStore`` (repro/data/
 edge_store.py) *host* residency is also |E|-independent: the only host
 buffers are the staging pair, filled from the store and overwritten in
-place once the in-flight transfer from their previous contents completes
-(``EdgeChunkStream.device_chunks``). The transfer is a forced-copy
-``jax.device_put`` so a staged buffer can never be aliased by the device
-array that compute reads.
+place once the device copy of their previous contents is ready
+(``EdgeChunkStream.device_chunks``). The transfer is
+``kernels/compat.device_put_copied``: a ``device_put`` followed by a
+device-side copy, and the copy is the array compute reads — so no array
+compute reads still refers to a staged buffer once it is refilled.
 
 Bit-exactness: every stage's one-shot function is a thin wrapper over the
 same chunk-update body (single chunk = whole list), and the SCoDA block
@@ -240,10 +241,14 @@ class EdgeChunkStream:
       safe even when the host→device transfer aliases host memory.
     * disk-backed source — ``device_chunks`` fills a small ring of
       persistent staging buffers (the pinned-staging analog; allocated
-      once, reused across chunks and passes) and transfers each with a
-      forced-copy ``device_put``, blocking on a buffer's previous transfer
-      only when the ring wraps. Plain iteration allocates a fresh buffer
-      per chunk instead, since yielded chunks may outlive the next read.
+      once, reused across chunks and passes) and transfers each with
+      ``device_put_copied``. What makes the reuse safe is the wait: before
+      a buffer is refilled, the device copy made from its previous
+      contents is blocked on, and once that copy is ready no array reads
+      the buffer. ``device_put`` alone gives no such point — on CPU its
+      result may alias the buffer for as long as it lives. Plain iteration
+      allocates a fresh buffer per chunk instead, since yielded chunks may
+      outlive the next read.
     """
 
     def __init__(self, source, n_nodes: int, chunk_size: int,
@@ -350,9 +355,10 @@ class EdgeChunkStream:
         In-memory sources dispatch ``put`` up to ``prefetch`` chunks ahead
         (chunks are immutable slices, so no staging is needed). Disk-backed
         sources run the double-buffered pipeline described in the class
-        docstring; their default ``put`` is a forced-copy ``device_put``,
-        and any caller-supplied ``put`` must also copy (StreamRunner's
-        sharded ``put`` does). ``start`` skips the first chunks — the
+        docstring; their default ``put`` is ``device_put_copied``, and any
+        caller-supplied ``put`` must return an array that no longer reads
+        the host buffer once it is ready (StreamRunner's ``put`` is
+        ``device_put_copied`` too). ``start`` skips the first chunks — the
         checkpoint/resume cursor (``stream_detect(resume=)``).
         """
         self.passes += 1
@@ -381,7 +387,7 @@ class EdgeChunkStream:
             b = i % nbuf
             if inflight[b] is not None:
                 # The ring wrapped: before overwriting this staging buffer,
-                # wait out the transfer that still reads from it.
+                # wait until the device copy made from it is ready.
                 t0 = time.perf_counter()
                 inflight[b].block_until_ready()
                 if stats is not None:

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.core import cms as cms_lib
 from repro.core.scoda import dense_labels
+from repro.kernels.cms import ops as cms_ops
 from repro.kernels.merge import ops as merge_ops
 from repro.kernels.merge.ref import SENTINEL, pack_keys, unpack_keys
 
@@ -294,6 +295,7 @@ def community_sizes(
 ) -> jnp.ndarray:
     """CMS-estimated community sizes (paper §4.1): one sketch update per node,
     weight = its true graph degree; queries beyond the live count are masked.
+    The update is ``kernels/cms`` (the one-hot Pallas kernel on TPU).
 
     With ``mesh`` the node keys are sharded over devices (padded to a
     multiple of the device count with the masked key -1) and the sketch is
@@ -309,7 +311,7 @@ def community_sizes(
         weights = jnp.concatenate([weights, jnp.zeros((pad,), jnp.float32)])
         sketch = cms_lib.sharded_update(mesh, cms_cfg)(sketch, keys, weights)
     else:
-        sketch = cms_lib.update(sketch, labels_dense, weights, cms_cfg)
+        sketch = cms_ops.update(sketch, labels_dense, weights, cms_cfg)
     sizes = cms_lib.query(cms_lib.finalize(sketch), jnp.arange(s_cap, dtype=jnp.int32), cms_cfg)
     return jnp.where(jnp.arange(s_cap) < n_supernodes, sizes, 0.0)
 

@@ -1,90 +1,83 @@
-"""API compatibility across JAX versions.
+"""Small JAX helpers shared by the kernels, the streaming engine and the
+launchers.
 
-- jax ≥ 0.5 renamed ``pltpu.TPUCompilerParams`` → ``pltpu.CompilerParams``;
-  kernels import the name from here so either version works.
-- ``jax.device_put`` grew ``may_alias``/``donate`` keywords (~0.4.31);
-  ``device_put_copied`` is the forced-copy transfer the double-buffered
-  staging path needs (reused host staging buffers must never be aliased
-  by the device array), degrading gracefully on older jax where CPU
-  ``device_put`` always copies.
-- ``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax`` and
-  its replication-check kwarg was renamed (``check_rep`` → ``check_vma``);
-  ``shard_map_compat`` papers over both so the sharded stream/layout paths
-  run on every CI jax pin.
+- ``CompilerParams`` is the Pallas TPU compiler-parameter class every
+  kernel passes to ``pallas_call``; ``resolve_backend`` is the one
+  dispatch rule of every ``kernels/*/ops.py`` wrapper.
+- ``shard_map_compat`` is ``jax.shard_map`` with the replication check off.
+- ``device_put_copied`` is the host→device transfer for reused host
+  staging buffers: the returned array never reads the host buffer again.
+- ``enable_compile_cache`` places JAX's persistent compilation cache.
 """
-import inspect
+import os
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-try:  # jax ≥ ~0.6 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # the 0.4.x/0.5.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
+CompilerParams = pltpu.CompilerParams
 
-_SHARD_MAP_PARAMS = inspect.signature(_shard_map).parameters
-if "check_rep" in _SHARD_MAP_PARAMS:
-    _NOCHECK = {"check_rep": False}
-elif "check_vma" in _SHARD_MAP_PARAMS:
-    _NOCHECK = {"check_vma": False}
-else:  # pragma: no cover - future jax with the check removed entirely
-    _NOCHECK = {}
+# The checkout root (src/repro/kernels/compat.py → three levels up).
+_CHECKOUT = Path(__file__).resolve().parents[3]
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def resolve_backend(backend: str) -> tuple[str, bool]:
+    """``(backend, interpret)`` for a kernel wrapper's ``backend`` argument.
+
+    "auto" is the Pallas kernel on a TPU and the XLA reference path
+    anywhere else; "pallas" off a TPU, and "interpret" anywhere, run the
+    kernel in interpret mode (how the CPU tests check it against "ref").
+    """
+    if backend == "auto":
+        backend = "pallas" if on_tpu() else "ref"
+    return backend, backend == "interpret" or not on_tpu()
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` with the static replication check disabled.
+    """``jax.shard_map`` with the static replication check disabled.
 
     The sharded stream/layout bodies return ``all_gather``-replicated
     values the checker cannot infer as replicated; disabling the check is
     the documented escape hatch and is bitwise-neutral.
     """
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_NOCHECK
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-# Signature probe only — executing a device_put here would initialize the
-# JAX backend as an import side effect of the whole repro.core package.
-_HAS_MAY_ALIAS = "may_alias" in inspect.signature(jax.device_put).parameters
 
 
 def device_put_copied(x, sharding=None):
-    """``jax.device_put`` that is guaranteed not to alias host memory."""
-    if _HAS_MAY_ALIAS:
-        return jax.device_put(x, sharding, may_alias=False, donate=False)
-    return jax.device_put(x, sharding)
+    """Transfer ``x`` so the result is independent of the host buffer.
 
-
-def enable_persistent_compilation_cache(path) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (created on
-    first write) and drop the size/compile-time floors so every executable
-    is cached. Restarted services then deserialize yesterday's
-    executables instead of recompiling them — without it, cold-start
-    compile dominates a tile server's first-request latency
-    (``launch/serve.py`` wires this into its start path).
-
-    Returns True when the cache engaged. The knob names have moved across
-    jax versions (``jax.config`` flags ≥ ~0.4.26, the
-    ``jax.experimental.compilation_cache`` module before), so this probes
-    and degrades to False — callers treat a cold cache as a slow start,
-    never an error.
+    ``jax.device_put`` may keep reading a host NumPy buffer after it
+    returns — on CPU the device array can alias it outright, whatever
+    ``may_alias`` says. The device-side copy below is the array handed
+    on: once it is ready it holds its own bytes, so a caller that blocks
+    on it may refill the host buffer (``EdgeChunkStream.device_chunks``).
     """
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except Exception:
-        try:  # pre-flag API
-            from jax.experimental.compilation_cache import compilation_cache
+    return jnp.copy(jax.device_put(x, sharding))
 
-            compilation_cache.set_cache_dir(str(path))
-        except Exception:
-            return False
-    for knob, value in (
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except Exception:  # older jax without the floor knobs: still cached
-            pass
-    return True
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    other directory is set here. Otherwise the cache lives in
+    ``.bgv-compile-cache/`` at the checkout root, found from this file's
+    path rather than the working directory, so every launcher of one
+    checkout shares one cache. The size and compile-time floors are
+    dropped so every executable is cached: a restarted process then loads
+    its programs instead of compiling them again.
+    """
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = str(_CHECKOUT / ".bgv-compile-cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

@@ -12,9 +12,9 @@ the caller's position dtype; the result is cast back.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import resolve_backend
 from repro.kernels.grid.ref import (
     bin_and_sort,
     bin_nodes,  # noqa: F401  (re-exported: binning shared by every backend)
@@ -25,12 +25,6 @@ from repro.kernels.grid.ref import (
 from repro.kernels.grid.tiled import far_field_pallas, near_field_pallas
 from repro.kernels.segment import ops as segment_ops
 
-
-def _resolve(backend: str) -> tuple[str, bool]:
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    interpret = backend == "interpret" or jax.default_backend() != "tpu"
-    return backend, interpret
 
 
 def cell_stats(
@@ -45,7 +39,7 @@ def cell_stats(
     One fused sorted segment-sum over [Σm·x, Σm·y, Σm]; empty cells get
     mass 0 (force-dead) and centroid 0.
     """
-    backend, _ = _resolve(backend)
+    backend, _ = resolve_backend(backend)
     data = jnp.concatenate(
         [pos_s * mass_s[:, None], mass_s[:, None]], axis=1)
     sums = segment_ops.segment_sum(
@@ -57,7 +51,7 @@ def cell_stats(
 
 def far_field(pos, mass, cell, ccent, cmass, kr: float, backend: str = "auto"):
     """Monopole far field (own cell excluded) → [n, 2]."""
-    backend, interpret = _resolve(backend)
+    backend, interpret = resolve_backend(backend)
     if backend == "ref":
         return far_field_ref(pos, mass, cell, ccent, cmass, kr)
     return far_field_pallas(pos, mass, cell, ccent, cmass, kr,
@@ -67,7 +61,7 @@ def far_field(pos, mass, cell, ccent, cmass, kr: float, backend: str = "auto"):
 def near_field_sorted(pos_s, mass_s, cell_s, kr: float, window: int,
                       backend: str = "auto"):
     """Banded same-cell near field over the sorted order → [n, 2] (sorted)."""
-    backend, interpret = _resolve(backend)
+    backend, interpret = resolve_backend(backend)
     if backend == "ref":
         return near_field_ref(pos_s, mass_s, cell_s, kr, window)
     return near_field_pallas(pos_s, mass_s, cell_s, kr, window,

@@ -95,6 +95,7 @@ def far_field_pallas(
     grid = (n_pad // ti, c_pad // tc)
     out = pl.pallas_call(
         functools.partial(_far_kernel, kr=kr, ti=ti, tc=tc),
+        name="grid_far_field",
         grid=grid,
         in_specs=[
             pl.BlockSpec((ti, 2), lambda i, j: (i, 0)),
@@ -187,6 +188,7 @@ def near_field_pallas(
     )
     out = pl.pallas_call(
         functools.partial(_near_kernel, kr=kr, ti=ti, window=window, nt=nt),
+        name="grid_near_field",
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((ti, 4), lambda i: (jnp.maximum(i - 1, 0), 0)),

@@ -3,9 +3,9 @@ searchsorted + scatter elsewhere (dispatch mirrors kernels/segment/ops.py).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import resolve_backend
 from repro.kernels.merge.ref import merge_combine_ref
 from repro.kernels.merge.sorted_merge import merge_combine_pallas
 
@@ -26,9 +26,7 @@ def merge_combine(
     0) padding last. Returns (oa, ob, ow, n): the union's smallest ``cap``
     pairs with combined weights, and the union's unique-pair count.
     """
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend, interpret = resolve_backend(backend)
     if backend == "ref":
         return merge_combine_ref(sa, sb, sw, ca, cb, cw, s_cap)
-    interpret = backend == "interpret" or jax.default_backend() != "tpu"
     return merge_combine_pallas(sa, sb, sw, ca, cb, cw, s_cap, interpret=interpret)

@@ -92,23 +92,27 @@ def scatter_combine_pallas(
     w_p = jnp.pad(w, pad)[None, :]
     grid = (cap_pad // tn, n_pad // blk)
     spec_in = pl.BlockSpec((1, blk), lambda t, b: (0, b))
-    spec_out = pl.BlockSpec((1, tn), lambda t, b: (t, 0))
+    # Outputs are one [1, cap_pad] row blocked (1, tn): the TPU lowering
+    # wants a block's last two dims divisible by (8, 128) or equal to the
+    # array's, which a (1, tn) block of a [tiles, tn] array is not.
+    spec_out = pl.BlockSpec((1, tn), lambda t, b: (0, t))
     oa, ob, ow = pl.pallas_call(
         functools.partial(_kernel, tn=tn, blk=blk),
+        name="merge_scatter_combine",
         grid=grid,
         in_specs=[spec_in] * 4,
         out_specs=[spec_out] * 3,
         out_shape=(
-            jax.ShapeDtypeStruct((cap_pad // tn, tn), jnp.int32),
-            jax.ShapeDtypeStruct((cap_pad // tn, tn), jnp.int32),
-            jax.ShapeDtypeStruct((cap_pad // tn, tn), jnp.float32),
+            jax.ShapeDtypeStruct((1, cap_pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, cap_pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, cap_pad), jnp.float32),
         ),
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(pos_p, a_p, b_p, w_p)
-    return oa.reshape(-1)[:cap], ob.reshape(-1)[:cap], ow.reshape(-1)[:cap]
+    return oa[0, :cap], ob[0, :cap], ow[0, :cap]
 
 
 def _pad_block(pos, a, b, w, blk: int):

@@ -3,9 +3,9 @@ elsewhere (dispatch mirrors kernels/segment and kernels/merge ops.py).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import resolve_backend
 from repro.kernels.raster.ref import (
     count_scatter_into_ref,
     count_scatter_ref,
@@ -13,12 +13,6 @@ from repro.kernels.raster.ref import (
 )
 from repro.kernels.raster.splat import count_scatter_pallas, disk_accum_pallas
 
-
-def _resolve(backend: str) -> tuple[str, bool]:
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    interpret = backend == "interpret" or jax.default_backend() != "tpu"
-    return backend, interpret
 
 
 def count_scatter(
@@ -28,7 +22,7 @@ def count_scatter(
     backend: str = "auto",
 ) -> jnp.ndarray:
     """[N] positions + [N] increments → [size] int32 counts (edge splat)."""
-    backend, interpret = _resolve(backend)
+    backend, interpret = resolve_backend(backend)
     if backend == "ref":
         return count_scatter_ref(pos, inc, size)
     return count_scatter_pallas(pos, inc, size, interpret=interpret)
@@ -44,7 +38,7 @@ def count_scatter_into(
     returning a fresh buffer (hot path of the renderer's chunk loop —
     in place when the caller donates ``acc``). ``inc=None`` = unit
     increments (takes the faster pre-sorted scatter on the ref path)."""
-    backend, interpret = _resolve(backend)
+    backend, interpret = resolve_backend(backend)
     if backend == "ref":
         return count_scatter_into_ref(acc, pos, inc)
     if inc is None:
@@ -65,7 +59,7 @@ def disk_accum(
     backend: str = "auto",
 ) -> jnp.ndarray:
     """Per-pixel disk coverage counts by color group, [n_groups, h, w]."""
-    backend, interpret = _resolve(backend)
+    backend, interpret = resolve_backend(backend)
     if backend == "ref":
         return disk_accum_ref(cx, cy, r, group, n_groups, h, w)
     return disk_accum_pallas(cx, cy, r, group, n_groups, h, w, interpret=interpret)

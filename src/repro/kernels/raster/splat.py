@@ -86,28 +86,31 @@ def count_scatter_pallas(
     # INT32_MAX pad keeps the tail block sorted and outside every tile.
     pos_p = jnp.pad(pos_s, (0, n_pad - n), constant_values=_INT32_MAX)[None, :]
     inc_p = jnp.pad(inc_s, (0, n_pad - n))[None, :]
+    # The accumulator is one [1, size_pad] row blocked (1, tn) — the TPU
+    # lowering's block rule (as in kernels/merge).
     if acc is None:
-        acc2d = jnp.zeros((size_pad // tn, tn), jnp.int32)
+        acc2d = jnp.zeros((1, size_pad), jnp.int32)
     else:
-        acc2d = jnp.pad(acc, (0, size_pad - size)).reshape(size_pad // tn, tn)
+        acc2d = jnp.pad(acc, (0, size_pad - size))[None, :]
     grid = (size_pad // tn, n_pad // blk)
     out = pl.pallas_call(
         functools.partial(_scatter_kernel, tn=tn, blk=blk),
+        name="raster_count_scatter",
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tn), lambda t, b: (t, 0)),
+            pl.BlockSpec((1, tn), lambda t, b: (0, t)),
             pl.BlockSpec((1, blk), lambda t, b: (0, b)),
             pl.BlockSpec((1, blk), lambda t, b: (0, b)),
         ],
-        out_specs=pl.BlockSpec((1, tn), lambda t, b: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((size_pad // tn, tn), jnp.int32),
+        out_specs=pl.BlockSpec((1, tn), lambda t, b: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((1, size_pad), jnp.int32),
         input_output_aliases={0: 0},
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(acc2d, pos_p, inc_p)
-    return out.reshape(-1)[:size]
+    return out[0, :size]
 
 
 def _disk_kernel(px_ref, py_ref, cx_ref, cy_ref, r_ref, g_ref, o_ref, *, gp: int, blk: int):
@@ -166,6 +169,7 @@ def disk_accum_pallas(
     grid = (p_pad // tp, n_pad // blk)
     out = pl.pallas_call(
         functools.partial(_disk_kernel, gp=gp, blk=blk),
+        name="raster_disk_accum",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tp), lambda t, b: (0, t)),

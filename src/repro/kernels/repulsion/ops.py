@@ -1,12 +1,12 @@
 """Jit'd public wrapper for the n-body repulsion kernel.
 
-Backend selection:
+Backend selection (``_resolve``):
   * TPU            → Pallas kernel (nbody.py)
   * CPU, small n   → dense jnp oracle (fast enough, exact)
   * CPU, large n   → j-chunked jnp scan (same math, bounded memory) —
                      interpret-mode Pallas is too slow for production CPU
                      use; the kernel itself is validated in interpret mode
-                     by tests/test_kernels_repulsion.py.
+                     by tests/test_kernels.py.
 """
 from __future__ import annotations
 
@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import on_tpu
 from repro.kernels.repulsion.nbody import repulsion_pallas
 from repro.kernels.repulsion.ref import EPS, repulsion_ref
 
@@ -104,6 +105,25 @@ def repulsion_chunked_rows(pos, mass, i0, nl: int, kr: float, radii=None,
     return acc
 
 
+def _resolve(backend: str, n: int) -> str:
+    """The auto dispatch, shared by ``repulsion`` and ``repulsion_rows`` so
+    a device of the sharded layout takes the path one device would."""
+    if backend != "auto":
+        return backend
+    if on_tpu():
+        return "pallas"
+    return "ref" if n <= 2048 else "chunked"
+
+
+def _pallas_inputs(pos, mass, radii, tile: int):
+    """Tile size and the kernel's padded (pos, mass, radii)."""
+    n = pos.shape[0]
+    t = min(tile, max(128, n))
+    n_pad = ((n + t - 1) // t) * t
+    rad = _pad(radii, n_pad) if radii is not None else jnp.zeros(n_pad, pos.dtype)
+    return t, _pad(pos, n_pad), _pad(mass, n_pad), rad
+
+
 def repulsion(pos, mass, kr: float, radii=None, backend: str = "auto",
               tile: int = 512):
     """FA2 repulsion forces. pos [n,2], mass [n] → [n,2].
@@ -112,20 +132,35 @@ def repulsion(pos, mass, kr: float, radii=None, backend: str = "auto",
     """
     n = pos.shape[0]
     use_radii = radii is not None
-    if backend == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        backend = "pallas" if on_tpu else ("ref" if n <= 2048 else "chunked")
+    backend = _resolve(backend, n)
     if backend == "ref":
         return repulsion_ref(pos, mass, kr, radii=radii)
     if backend == "chunked":
         return repulsion_chunked(pos, mass, kr, radii=radii, use_radii=use_radii)
     # pallas (or explicit interpret validation)
-    interpret = backend == "interpret" or jax.default_backend() != "tpu"
-    t = min(tile, max(128, n))
-    n_pad = ((n + t - 1) // t) * t
-    pos_p = _pad(pos, n_pad)
-    mass_p = _pad(mass, n_pad)
-    rad_p = _pad(radii, n_pad) if use_radii else jnp.zeros(n_pad, pos.dtype)
+    t, pos_p, mass_p, rad_p = _pallas_inputs(pos, mass, radii, tile)
     out = repulsion_pallas(pos_p, mass_p, rad_p, kr, ti=t, tj=t,
-                           use_radii=use_radii, interpret=interpret)
+                           use_radii=use_radii,
+                           interpret=backend == "interpret" or not on_tpu())
     return out[:n]
+
+
+def repulsion_rows(pos, mass, i0, nl: int, kr: float, radii=None,
+                   backend: str = "auto", tile: int = 512):
+    """Rows [i0, i0+nl) of ``repulsion`` with the same backend and the same
+    bits, computing only those rows where the backend allows (``i0`` may
+    be traced) — a device's share of the sharded layout."""
+    n = pos.shape[0]
+    use_radii = radii is not None
+    backend = _resolve(backend, n)
+    if backend == "ref":
+        full = repulsion_ref(pos, mass, kr, radii=radii)
+        return jax.lax.dynamic_slice_in_dim(full, i0, nl)
+    if backend == "chunked":
+        return repulsion_chunked_rows(pos, mass, i0, nl, kr, radii=radii,
+                                      use_radii=use_radii)
+    t, pos_p, mass_p, rad_p = _pallas_inputs(pos, mass, radii, tile)
+    ti = t if nl % t == 0 else nl
+    return repulsion_pallas(pos_p, mass_p, rad_p, kr, ti=ti, tj=t,
+                            use_radii=use_radii, i0=i0, rows=nl,
+                            interpret=backend == "interpret" or not on_tpu())

@@ -1,9 +1,9 @@
 """Jit'd public wrapper: segment-sum via Pallas on TPU, XLA scatter on CPU."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels.compat import resolve_backend
 from repro.kernels.segment.ref import segment_sum_ref
 from repro.kernels.segment.seg_matmul import segment_sum_pallas
 
@@ -18,11 +18,9 @@ def segment_sum(
     """``indices_are_sorted`` promises sorted ``seg_ids`` (same result,
     faster scatter lowering on the ref path; the one-hot-matmul Pallas
     kernel is insensitive to input order and ignores the hint)."""
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend, interpret = resolve_backend(backend)
     if backend == "ref":
         return segment_sum_ref(
             data, seg_ids, n_segments, indices_are_sorted=indices_are_sorted
         )
-    interpret = backend == "interpret" or jax.default_backend() != "tpu"
     return segment_sum_pallas(data, seg_ids, n_segments, interpret=interpret)

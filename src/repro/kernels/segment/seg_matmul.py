@@ -37,8 +37,11 @@ def _kernel(seg_ref, data_ref, o_ref, *, tn: int, blk: int):
     onehot = jnp.where(row_ids == local[None, :], 1.0, 0.0)  # [tn, blk]
     # Accumulate in f32 regardless of input dtype (production practice);
     # the wrapper casts back once at the end.
+    # HIGHEST: a single bf16 MXU pass would round the f32 messages.
     o_ref[...] += jnp.dot(
-        onehot, data_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32
+        onehot, data_ref[...].astype(jnp.float32),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -59,6 +62,7 @@ def segment_sum_pallas(
     grid = (n_pad // tn, e_pad // blk)
     out = pl.pallas_call(
         functools.partial(_kernel, tn=tn, blk=blk),
+        name="segment_sum",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, blk), lambda t, b: (0, b)),

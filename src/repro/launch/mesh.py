@@ -1,20 +1,31 @@
 """Production meshes. A FUNCTION (not a module-level constant) so importing
-this module never touches jax device state."""
+this module never touches jax device state.
+
+Every mesh here has ``Auto`` axes: the sharded bodies place their own
+collectives with ``shard_map``, and an array on an ``Explicit`` mesh (what
+``jax.make_mesh`` gives by default) cannot enter a jitted function that
+has no mesh in context — the unsharded chunk updates are such functions.
+"""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh with the production axis names — lets the same
     sharded step functions run on one CPU device (smoke tests, examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_stream_mesh(devices: int | None = None):
@@ -25,4 +36,4 @@ def make_stream_mesh(devices: int | None = None):
     """
     avail = jax.device_count()
     d = avail if devices is None else min(devices, avail)
-    return jax.make_mesh((d,), ("data",))
+    return _auto_mesh((d,), ("data",))

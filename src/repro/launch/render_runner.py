@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core.pipeline import biggraphvis, default_config, full_layout_colored
 from repro.data.edge_store import open_edge_store
+from repro.kernels.compat import enable_compile_cache
 from repro.obs.cli import add_obs_args, obs_session
 from repro.obs.metrics import REGISTRY
 from repro.render import RenderConfig, render_arrays, write_png
@@ -90,6 +91,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=5)
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     with obs_session(args):
         _run(args)

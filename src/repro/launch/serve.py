@@ -10,11 +10,12 @@ levels into the LRU cache, and serves a synthetic zipfian pan/zoom trace,
 reporting tiles/s, cache hit rate, miss-latency percentiles, and the
 steady-state recompile count (which should be zero — fixed tile shapes).
 
-The start path points JAX's persistent compilation cache at
-``--compile-cache`` (default ``.bgv-compile-cache/``; ``--no-compile-cache``
-disables), so a restarted service deserializes its compiled render/layout
-steps instead of recompiling them — cold-start compile otherwise dominates
-first-request latency. The former LM decode demo lives on in
+The start path turns on JAX's persistent compilation cache
+(``kernels/compat.enable_compile_cache``: ``$JAX_COMPILATION_CACHE_DIR``
+where set, else ``.bgv-compile-cache/`` at the checkout root;
+``--no-compile-cache`` disables), so a restarted service deserializes its
+compiled render/layout steps instead of recompiling them — cold-start
+compile otherwise dominates first-request latency. The former LM decode demo lives on in
 ``examples/serve_lm.py`` (engine: ``repro/serve/engine.py``).
 """
 from __future__ import annotations
@@ -24,7 +25,7 @@ import time
 
 import numpy as np
 
-from repro.kernels.compat import enable_persistent_compilation_cache
+from repro.kernels.compat import enable_compile_cache
 from repro.obs.cli import add_obs_args, obs_session
 
 
@@ -60,8 +61,6 @@ def main() -> None:
     ap.add_argument("--drill-frac", type=float, default=0.05,
                     help="fraction of requests drilling into a community")
     ap.add_argument("--seed", type=int, default=0, help="traffic seed")
-    ap.add_argument("--compile-cache", default=".bgv-compile-cache",
-                    help="persistent XLA compilation cache directory")
     ap.add_argument("--no-compile-cache", action="store_true",
                     help="skip persistent compilation caching")
     add_obs_args(ap)
@@ -74,10 +73,8 @@ def main() -> None:
 def _run(args) -> None:
     # Before any compilation: a warm cache turns the service's cold-start
     # compiles into deserialization.
-    cache_on = False
-    if not args.no_compile_cache:
-        cache_on = enable_persistent_compilation_cache(args.compile_cache)
-    print(f"compile cache: {'on (' + args.compile_cache + ')' if cache_on else 'off'}")
+    cache = None if args.no_compile_cache else enable_compile_cache()
+    print(f"compile cache: {cache or 'off'}")
 
     import jax
 

@@ -3,9 +3,9 @@
 Owns device placement for the chunk buffers: single-device by default, or
 row-sharded across a mesh's devices (the per-chunk scatter updates then
 merge through XLA's all-reduce — the same collective structure as the
-``bgv_detect`` dry-run cells in launch/steps.py). Transfers are forced-copy
-``device_put``s (kernels/compat.py) so the engine's reusable staging
-buffers are never aliased by device arrays, and the engine overlaps them
+``bgv_detect`` dry-run cells in launch/steps.py). Transfers are
+``device_put_copied`` (kernels/compat.py), whose result stops reading the
+engine's reusable staging buffers once it is ready, and the engine overlaps them
 with compute via its double-buffered staging ring
 (``EdgeChunkStream.device_chunks``).
 
@@ -34,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.pipeline import BGVConfig, BGVResult, biggraphvis
 from repro.core.stream import StreamConfig, oneshot_device_bytes
 from repro.data.edge_store import write_bin, write_npy, write_shards
-from repro.kernels.compat import device_put_copied
+from repro.kernels.compat import device_put_copied, enable_compile_cache
 from repro.obs.cli import add_obs_args, obs_session
 from repro.resilience.checkpoint import Preempted, StreamCheckpointer
 
@@ -51,8 +51,8 @@ class StreamRunner:
     ``put`` is handed to the engine as the host→device transfer; with a mesh
     it places each chunk row-sharded over every mesh axis, so each device
     streams its own slice of the chunk (edge shards, DESIGN.md §4). Either
-    way it copies (never aliases host memory), as the engine's staged disk
-    path requires.
+    way it is ``device_put_copied``, whose result no longer reads the host
+    buffer once ready, as the engine's staged disk path requires.
 
     A chunk whose row count doesn't divide by the mesh device count can't
     be row-sharded; ``put`` pads it to the next multiple with the engine's
@@ -189,6 +189,7 @@ def main() -> None:
                          "--xla_force_host_platform_device_count=N)")
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     with obs_session(args):
         _run(args)

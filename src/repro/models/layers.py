@@ -7,23 +7,10 @@ Everything is a pure function over (params dict, inputs); activations use
 """
 from __future__ import annotations
 
-import inspect as _inspect
-
 import jax
 import jax.numpy as jnp
 
-# Newer jax exposes shard_map at the top level; older versions keep it in
-# jax.experimental. The replication-check kwarg was also renamed
-# (check_rep → check_vma) on a different schedule, so pick it by signature.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHMAP_NOCHECK = {
-    ("check_vma" if "check_vma" in _inspect.signature(_shard_map).parameters
-     else "check_rep"): False
-}
+from repro.kernels.compat import shard_map_compat
 
 # --------------------------------------------------------------- norms / pos
 
@@ -288,12 +275,11 @@ def moe_mlp_shmap(x, router_w, w_gate, w_in, w_out, *, top_k: int,
         return out.reshape(bl, sl, dl), aux
 
     tok = tuple(token_axes) if token_axes else None
-    out, aux = _shard_map(
+    out, aux = shard_map_compat(
         local_fn,
-        mesh=mesh,
+        mesh,
         in_specs=(P(tok, None, None), P(), P(expert_axis, None, None),
                   P(expert_axis, None, None), P(expert_axis, None, None)),
         out_specs=(P(tok, None, None), P()),
-        **_SHMAP_NOCHECK,
     )(x, router_w, w_gate, w_in, w_out)
     return out, aux

@@ -1,12 +1,13 @@
 """JAX-facing meters: compile events, device/host memory, XLA profiles.
 
-* ``jit_compile_count`` — monotone count of XLA backend compiles via
-  ``jax.monitoring`` (moved here from ``repro/serve/tiles.py``; the old
-  import path re-exports). Listener registration is **idempotent**: one
-  process-wide listener whatever the import path or how many engines are
-  constructed — the pre-move code could double-register (and so
-  double-count) if a second registration path ever ran. Compile durations
-  also land in the ``jax.compile_seconds`` histogram.
+* ``jit_compile_count`` — monotone count of programs compiled (or loaded
+  from the persistent cache) via ``jax.monitoring`` (moved here from
+  ``repro/serve/tiles.py``; the old import path re-exports). Listener
+  registration is **idempotent**: one process-wide listener whatever the
+  import path or how many engines are constructed — the pre-move code
+  could double-register (and so double-count) if a second registration
+  path ever ran. Compile durations also land in the
+  ``jax.compile_seconds`` histogram.
 * ``update_memory_gauges`` — snapshot ``jax.live_arrays()`` bytes and
   per-device allocator peaks (``device.memory_stats()`` where the backend
   reports them; CPU typically doesn't) into ``jax.*`` gauges.
@@ -52,11 +53,13 @@ def register_compile_listener() -> bool:
 
 
 def jit_compile_count() -> int:
-    """Monotone count of XLA backend compiles in this process (cache hits
-    — including persistent-cache hits — do not fire the event). Counting
-    starts at the first call; callers take deltas. The serve benchmark's
-    "steady-state ticks trigger zero recompilation" check is a flat delta
-    across the measured phase."""
+    """Monotone count of programs this process handed to XLA: each one
+    compiled, or loaded from the persistent compilation cache (on jax 0.9
+    the event wraps both; a load records only its short load time).
+    In-memory jit cache hits do not fire it. Counting starts at the first
+    call; callers take deltas. The serve benchmark's "steady-state ticks
+    trigger zero recompilation" check is a flat delta across the measured
+    phase."""
     register_compile_listener()
     return int(REGISTRY.counter("jax.compiles").value)
 

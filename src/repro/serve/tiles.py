@@ -31,7 +31,7 @@ Bit-exactness contract: a served pyramid tile equals a direct one-shot
 direct ``full_layout_colored`` + fitted render of the same community
 (tests/test_tiles.py; ``serve_bench --check`` re-verifies on live
 traffic). Persistent compilation caching for the service start path is
-``repro.kernels.compat.enable_persistent_compilation_cache``.
+``repro.kernels.compat.enable_compile_cache``.
 """
 from __future__ import annotations
 
@@ -421,6 +421,7 @@ class TileEngine:
         self.served = 0
         self.rendered = 0
         self.failed = 0
+        self.last_error: Exception | None = None  # newest render failure
         self.shed = 0
         self.render_s = 0.0
         ensure_error_counters()
@@ -512,9 +513,10 @@ class TileEngine:
             for spec in batch:
                 try:
                     tiles[spec] = self.pyramid.render_tile(spec)
-                except Exception:
+                except Exception as e:
                     broken.add(spec)
                     self.failed += 1
+                    self.last_error = e
                     REGISTRY.counter("errors.failed_tiles").inc()
         tick_s = time.perf_counter() - t0
         self.render_s += tick_s

@@ -201,6 +201,27 @@ def test_repulsion_chunked_rows_bitwise():
         assert np.array_equal(np.asarray(full[i0:i0 + nl]), np.asarray(part))
 
 
+@pytest.mark.parametrize("backend,n,rows", [
+    ("ref", 256, ((0, 64), (192, 64))),
+    ("chunked", 2304, ((0, 576), (1728, 576))),
+    ("interpret", 512, ((0, 128), (384, 128), (128, 16))),
+])
+def test_repulsion_rows_bitwise(backend, n, rows):
+    """``repulsion_rows`` (what each device of the sharded layout calls)
+    is bitwise the matching rows of ``repulsion`` on the same backend —
+    including the Pallas kernel's target-row form, here interpreted."""
+    rng = np.random.default_rng(n)
+    pos = jnp.asarray(rng.uniform(-50, 50, size=(n, 2)), jnp.float32)
+    mass = jnp.asarray(rng.uniform(1.0, 9.0, size=n), jnp.float32)
+    radii = jnp.sqrt(mass)
+    full = rep_ops.repulsion(pos, mass, 80.0, radii=radii, backend=backend,
+                             tile=128)
+    for i0, nl in rows:
+        part = rep_ops.repulsion_rows(pos, mass, jnp.int32(i0), nl, 80.0,
+                                      radii=radii, backend=backend, tile=128)
+        assert np.array_equal(np.asarray(full[i0:i0 + nl]), np.asarray(part))
+
+
 def test_near_field_rows_bitwise():
     """Halo near field on row blocks == slicing the full banded near field."""
     rng = np.random.default_rng(1)

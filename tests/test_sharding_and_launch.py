@@ -244,3 +244,30 @@ def test_lm_stream_deterministic_and_sharded():
     left = s.batch_at(5, shard=(0, 4))["tokens"]
     right = s.batch_at(5, shard=(4, 4))["tokens"]
     np.testing.assert_array_equal(np.concatenate([left, right]), a["tokens"])
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/cache/dir"])
+def test_enable_compile_cache_placement(monkeypatch, env_dir):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and no other directory is set;
+    without it the cache sits at the checkout root, whatever the cwd."""
+    from pathlib import Path
+
+    from repro.kernels import compat
+
+    updates = {}
+    monkeypatch.setattr(compat.jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    if env_dir is None:
+        monkeypatch.delenv(compat.CACHE_ENV, raising=False)
+    else:
+        monkeypatch.setenv(compat.CACHE_ENV, env_dir)
+    monkeypatch.chdir("/")
+    path = compat.enable_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    if env_dir is None:
+        assert path == str(root / ".bgv-compile-cache")
+        assert updates["jax_compilation_cache_dir"] == path
+    else:
+        assert path == env_dir
+        assert "jax_compilation_cache_dir" not in updates
+    assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0

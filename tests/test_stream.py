@@ -270,3 +270,18 @@ def test_stream_rejects_wrong_dtype_at_construction(graph):
         EdgeChunkStream(edges.astype(np.float64), n, 128)
     with pytest.raises(ValueError, match=r"shape \[E, 2\]"):
         EdgeChunkStream(edges.reshape(-1), n, 128)
+
+
+def test_device_put_copied_survives_buffer_refill():
+    """The staging ring refills a host buffer once the array made from it
+    is ready; that array must keep the old contents (a bare device_put
+    may alias the host buffer on CPU)."""
+    from repro.kernels.compat import device_put_copied
+
+    buf = np.empty((128, 2), np.int32)
+    for t in range(64):
+        buf[:] = t
+        dev = device_put_copied(buf)
+        dev.block_until_ready()
+        buf[:] = -1
+        assert (np.asarray(dev) == t).all()
