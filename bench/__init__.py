@@ -1,0 +1,1 @@
+"""BigGraphVis on-chip benchmark: ``python3 bench/run.py --workload <cell> ...``."""

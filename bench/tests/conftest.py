@@ -1,0 +1,9 @@
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+CHECKOUT = Path(__file__).resolve().parents[2]
+for p in (str(CHECKOUT / "src"), str(CHECKOUT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
