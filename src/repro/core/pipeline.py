@@ -308,22 +308,23 @@ def biggraphvis(
         )
 
         groups = color_groups(sg.sizes)
-    result = BGVResult(
-        positions=np.asarray(pos),
-        sizes=np.asarray(sg.sizes),
-        groups=np.asarray(groups),
-        labels=np.asarray(sg.labels),
-        supergraph=sg,
-        modularity=float(q),
-        n_supernodes=int(sg.n_supernodes),
-        n_superedges=int(sg.n_superedges),
-        timings=t,
-        stream=stats,
-        # Only carry an *explicit* tracer; global-tracer users keep the
-        # late-binding get_tracer() fallback in .render().
-        obs=cfg.obs if cfg.obs is not None
-        else (stream.obs if stream is not None else None),
-    )
+        with tr.span("biggraphvis.fetch"):  # device → host copies
+            result = BGVResult(
+                positions=np.asarray(pos),
+                sizes=np.asarray(sg.sizes),
+                groups=np.asarray(groups),
+                labels=np.asarray(sg.labels),
+                supergraph=sg,
+                modularity=float(q),
+                n_supernodes=int(sg.n_supernodes),
+                n_superedges=int(sg.n_superedges),
+                timings=t,
+                stream=stats,
+                # Only carry an *explicit* tracer; global-tracer users keep
+                # the late-binding get_tracer() fallback in .render().
+                obs=cfg.obs if cfg.obs is not None
+                else (stream.obs if stream is not None else None),
+            )
     if render_path is not None or render_cfg is not None:
         _warn_render_kwargs()
         result.render(render_path, cfg=render_cfg)

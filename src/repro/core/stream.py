@@ -91,9 +91,6 @@ class StreamConfig:
     ``agg_backend`` selects the superedge-aggregation algorithm ("merge" =
     two-level sorted-merge via kernels/merge, "lexsort" = full re-sort
     baseline; bit-identical below capacity — core/supergraph.py).
-    ``time_agg`` blocks on every aggregation update to fill the per-chunk
-    ``StreamStats`` aggregation timing (costs copy/compute overlap; leave
-    off outside benchmarks).
 
     Multi-device (DESIGN.md §2, ROADMAP item 1): ``mesh`` + ``shard_detect``
     lower every per-chunk edge pass (SCoDA labels, degrees, superedge
@@ -118,7 +115,6 @@ class StreamConfig:
     chunk_size: int = 1 << 16  # edges resident on device per chunk
     prefetch: int = 1  # host→device copies dispatched ahead of compute
     agg_backend: str = "merge"  # superedge aggregation: "merge" | "lexsort"
-    time_agg: bool = False  # per-chunk aggregation timing in StreamStats
     mesh: object = None  # jax.sharding.Mesh for the sharded paths (or None)
     shard_detect: bool = False  # shard the per-chunk edge passes over mesh
     shard_layout: bool = False  # node-partition the FA2 layout over mesh
@@ -135,12 +131,12 @@ class StreamStats:
     in-memory, staging buffers only when disk-backed). ``host_fill_s`` is
     time spent reading the store into staging; ``copy_stall_s`` is time
     blocked waiting for an in-flight transfer before a staging buffer could
-    be reused — both ≈ 0 when copies overlap compute. ``agg_update_s`` /
-    ``agg_chunks`` are the blocking per-chunk superedge-aggregation timing,
-    populated only under ``StreamConfig.time_agg`` (benchmarks/agg_bench.py
-    compares them across ``agg_backend`` values). ``raster_update_s`` /
-    ``raster_chunks`` are their per-chunk analogue for the renderer's
-    streamed edge-splat pass (repro/render/raster.py, populated under
+    be reused. That copy queues behind every program dispatched before it,
+    so ``copy_stall_s`` is mostly the host held back by device work; the
+    device sits idle only where a profiled run's ``stream.stall`` spans
+    hold no device operation. ``raster_update_s`` / ``raster_chunks`` are
+    the blocking per-chunk timing of the renderer's streamed edge-splat
+    pass (repro/render/raster.py, populated under
     ``RenderConfig.time_raster``; benchmarks/render_bench.py).
 
     ``devices`` is the mesh size the sharded passes actually ran on (1 =
@@ -161,8 +157,6 @@ class StreamStats:
     peak_host_bytes: int = 0
     host_fill_s: float = 0.0
     copy_stall_s: float = 0.0
-    agg_update_s: float = 0.0
-    agg_chunks: int = 0
     raster_update_s: float = 0.0
     raster_chunks: int = 0
     stage_seconds: dict = field(default_factory=dict)
@@ -204,7 +198,6 @@ class StreamStats:
             ("stream.devices", self.devices),
             ("stream.host_fill_s", self.host_fill_s),
             ("stream.copy_stall_s", self.copy_stall_s),
-            ("stream.agg_update_s", self.agg_update_s),
             ("stream.raster_update_s", self.raster_update_s),
         ):
             reg.gauge(name).set(value)
@@ -349,7 +342,8 @@ class EdgeChunkStream:
         return self._host_chunks()
 
     def device_chunks(self, put=None, prefetch: int = 1,
-                      stats: StreamStats | None = None, start: int = 0):
+                      stats: StreamStats | None = None, start: int = 0,
+                      tracer=None):
         """One pass of device-resident chunks, transfers overlapping compute.
 
         In-memory sources dispatch ``put`` up to ``prefetch`` chunks ahead
@@ -359,7 +353,11 @@ class EdgeChunkStream:
         caller-supplied ``put`` must return an array that no longer reads
         the host buffer once it is ready (StreamRunner's ``put`` is
         ``device_put_copied`` too). ``start`` skips the first chunks — the
-        checkpoint/resume cursor (``stream_detect(resume=)``).
+        checkpoint/resume cursor (``stream_detect(resume=)``). On the disk
+        path ``tracer`` (None = process-global) spans each chunk's
+        ``stream.stall`` (the wait ``copy_stall_s`` adds up),
+        ``stream.fill`` (the store read) and ``stream.put`` (the transfer's
+        dispatch).
         """
         self.passes += 1
         depth = max(0, prefetch)
@@ -370,6 +368,7 @@ class EdgeChunkStream:
             return
 
         put = put or device_put_copied
+        tr = tracer if tracer is not None else get_tracer()
         nbuf = self.staging_buffers(depth)
         if self._staging is None or len(self._staging) < nbuf:
             self._staging = [
@@ -388,16 +387,19 @@ class EdgeChunkStream:
             if inflight[b] is not None:
                 # The ring wrapped: before overwriting this staging buffer,
                 # wait until the device copy made from it is ready.
-                t0 = time.perf_counter()
-                inflight[b].block_until_ready()
-                if stats is not None:
-                    stats.copy_stall_s += time.perf_counter() - t0
+                with tr.span("stream.stall", chunk=i):
+                    t0 = time.perf_counter()
+                    inflight[b].block_until_ready()
+                    if stats is not None:
+                        stats.copy_stall_s += time.perf_counter() - t0
                 inflight[b] = None
-            t0 = time.perf_counter()
-            buf = self._read_chunk(i, self._staging[b])
-            if stats is not None:
-                stats.host_fill_s += time.perf_counter() - t0
-            dev = put(buf)
+            with tr.span("stream.fill", chunk=i):
+                t0 = time.perf_counter()
+                buf = self._read_chunk(i, self._staging[b])
+                if stats is not None:
+                    stats.host_fill_s += time.perf_counter() - t0
+            with tr.span("stream.put", chunk=i):
+                dev = put(buf)
             inflight[b] = dev
             pending.append(dev)
             if len(pending) > depth:
@@ -570,7 +572,8 @@ def stream_detect(
             c0 = start_chunk if r == start_round else 0
             with tr.span("detect.round", round=r):
                 for i, chunk in enumerate(
-                    stream.device_chunks(put, prefetch, stats, start=c0),
+                    stream.device_chunks(put, prefetch, stats, start=c0,
+                                         tracer=tr),
                     start=c0,
                 ):
                     with tr.span("detect.chunk", round=r, chunk=i):
@@ -620,7 +623,6 @@ def stream_supergraph(
     stats: StreamStats | None = None,
     with_modularity: bool = True,
     agg_backend: str = "merge",
-    time_agg: bool = False,
     mesh=None,
     shard: bool = False,
     tracer=None,
@@ -638,8 +640,8 @@ def stream_supergraph(
 
     CMS community sizing is node-keyed (one sketch update per node, weight =
     graph degree) and so needs no edge pass. Returns (Supergraph, Q) with Q
-    None when ``with_modularity`` is false. ``agg_backend``/``time_agg``
-    are the ``StreamConfig`` aggregation knobs (see its docstring).
+    None when ``with_modularity`` is false. ``agg_backend`` is the
+    ``StreamConfig`` aggregation knob (see its docstring).
 
     With ``mesh`` + ``shard`` the aggregation/modularity chunk updates and
     the node-keyed CMS sizing run device-sharded (bit-identical —
@@ -702,18 +704,12 @@ def stream_supergraph(
             return out
 
         for i, chunk in enumerate(
-            stream.device_chunks(put, prefetch, stats, start=start_chunk),
+            stream.device_chunks(put, prefetch, stats, start=start_chunk,
+                                 tracer=tr),
             start=start_chunk,
         ):
             with tr.span("supergraph.chunk", chunk=i):
-                if time_agg and stats is not None:
-                    t0 = time.perf_counter()
-                    agg = one_agg(agg, chunk, agg_ext)
-                    jax.block_until_ready(agg)
-                    stats.agg_update_s += time.perf_counter() - t0
-                    stats.agg_chunks += 1
-                else:
-                    agg = one_agg(agg, chunk, agg_ext)
+                agg = one_agg(agg, chunk, agg_ext)
                 if with_modularity:
                     mod = mod_upd(mod, chunk, mod_ext)
             if stats is not None:
@@ -852,7 +848,7 @@ def stream_pipeline(
             stream, labels, gdeg, n_nodes, s_cap, max_super_edges, cms_cfg,
             put=put, prefetch=cfg.prefetch, stats=stats,
             with_modularity=with_modularity,
-            agg_backend=cfg.agg_backend, time_agg=cfg.time_agg,
+            agg_backend=cfg.agg_backend,
             mesh=cfg.mesh, shard=cfg.shard_detect, tracer=tr,
             ckpt=checkpoint, resume=resume_sg,
         )

@@ -7,7 +7,8 @@ Three zero-dependency pieces:
 
 * ``Tracer`` (``repro.obs.trace``) — nested wall-clock spans via context
   managers with thread-local span stacks, exported as Chrome
-  trace-event/Perfetto JSON, JSONL, or an indented text tree.
+  trace-event/Perfetto JSON, JSONL, or an indented text tree. An enabled
+  tracer also writes each span into a running ``jax.profiler`` trace.
 * ``MetricsRegistry`` (``repro.obs.metrics``) — process-global named
   counters / gauges / log-bucket histograms (p50/p99 without numpy);
   ``REGISTRY`` is the global instance the stats dataclasses publish into.

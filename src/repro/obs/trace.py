@@ -17,10 +17,18 @@ device time block inside their span exactly where the pre-obs code called
 ``block_until_ready`` — the tracer never adds synchronization of its own,
 which is how the ``benchmarks/obs_bench`` ≤ 3 % overhead gate holds.
 
+An enabled tracer's span also enters a ``jax.profiler.TraceAnnotation``
+of the same name, so while a profiler trace is running (``--profile``)
+every span lands in it as a host event on the device trace's clock, and
+an idle stretch of the device can be read by what the host was doing.
+The annotation carries the name only: its keyword form would encode the
+attributes into the event name. ``jax`` is imported at the first enabled
+span, so this module stays importable without it.
+
 Disabled tracers (``Tracer(enabled=False)``, the module's ``NULL_TRACER``,
 and the process-global default before ``enable_tracing()``) return a
 shared no-op span: one attribute check + one call per ``span()``, no
-allocation, no lock.
+allocation, no lock, no annotation.
 
 Exports: Chrome trace-event JSON (``to_chrome`` — loadable by Perfetto /
 ``chrome://tracing``), JSON-lines (``to_jsonl``), and an indented text
@@ -73,10 +81,23 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _SpanHandle:
-    """Live (open) span: context manager pushed on the thread's stack."""
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, bound at first use
 
-    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent", "t0")
+
+def _annotation(name: str):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
+class _SpanHandle:
+    """Live (open) span: context manager pushed on the thread's stack,
+    mirrored by a profiler annotation of the same name."""
+
+    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -96,11 +117,14 @@ class _SpanHandle:
         if stack:
             self.parent = stack[-1].span_id
         stack.append(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.t0 = self._tracer._now()
         return self
 
     def __exit__(self, *exc):
         t1 = self._tracer._now()
+        self._ann.__exit__(None, None, None)
         stack = self._tracer._stack()
         # Tolerate out-of-order exits (a caller leaking a span) by popping
         # back to this handle instead of corrupting deeper frames.

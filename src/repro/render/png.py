@@ -5,7 +5,8 @@ hosts, same constraint that shaped ``coloring.write_svg``).
 the simplest spec-conformant stream, readable by any viewer. ``read_png``
 is the matching subset decoder (8-bit RGB/RGBA, filters 0–2, single image)
 used by the round-trip tests and the CI ``render-smoke`` content check; it
-is not a general PNG reader.
+is not a general PNG reader. ``write_png`` runs inside a ``render.png``
+span (``repro.obs``), so a profiled run shows the encode as host time.
 """
 from __future__ import annotations
 
@@ -13,6 +14,8 @@ import struct
 import zlib
 
 import numpy as np
+
+from repro.obs.trace import get_tracer
 
 _SIGNATURE = b"\x89PNG\r\n\x1a\n"
 
@@ -26,25 +29,28 @@ def _chunk(tag: bytes, payload: bytes) -> bytes:
     )
 
 
-def write_png(path: str, image: np.ndarray) -> str:
-    """Write an [H, W, 3] uint8 RGB image; returns ``path``."""
+def write_png(path: str, image: np.ndarray, tracer=None) -> str:
+    """Write an [H, W, 3] uint8 RGB image; returns ``path``. ``tracer``
+    holds the ``render.png`` span (None = process-global tracer)."""
     img = np.asarray(image)
     if img.ndim != 3 or img.shape[2] != 3 or img.dtype != np.uint8:
         raise ValueError(
             f"write_png expects [H, W, 3] uint8, got {img.shape} {img.dtype}"
         )
     h, w = img.shape[:2]
-    ihdr = struct.pack(">IIBBBBB", w, h, 8, 2, 0, 0, 0)  # 8-bit truecolor
-    # Filter byte 0 (None) before every scanline.
-    raw = np.empty((h, 1 + w * 3), np.uint8)
-    raw[:, 0] = 0
-    raw[:, 1:] = img.reshape(h, w * 3)
-    idat = zlib.compress(raw.tobytes(), 6)
-    with open(path, "wb") as f:
-        f.write(_SIGNATURE)
-        f.write(_chunk(b"IHDR", ihdr))
-        f.write(_chunk(b"IDAT", idat))
-        f.write(_chunk(b"IEND", b""))
+    tr = tracer if tracer is not None else get_tracer()
+    with tr.span("render.png", height=h, width=w):
+        ihdr = struct.pack(">IIBBBBB", w, h, 8, 2, 0, 0, 0)  # 8-bit truecolor
+        # Filter byte 0 (None) before every scanline.
+        raw = np.empty((h, 1 + w * 3), np.uint8)
+        raw[:, 0] = 0
+        raw[:, 1:] = img.reshape(h, w * 3)
+        idat = zlib.compress(raw.tobytes(), 6)
+        with open(path, "wb") as f:
+            f.write(_SIGNATURE)
+            f.write(_chunk(b"IHDR", ihdr))
+            f.write(_chunk(b"IDAT", idat))
+            f.write(_chunk(b"IEND", b""))
     return str(path)
 
 

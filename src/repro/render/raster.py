@@ -409,24 +409,27 @@ def render_arrays(
     edge_acc = None
     sstats = None
     if cfg.draw_edges and edge_source is not None:
-        store = as_edge_store(edge_source)
-        stream = EdgeChunkStream(store, n, cfg.chunk_size)
-        sstats = StreamStats(chunk_size=stream.chunk_size)
-        pxy_ext = jnp.asarray(
-            np.concatenate([np.stack([px, py], 1), [[0.0, 0.0]]]).astype(
-                np.float32
+        with tr.span("render.edge_setup"):
+            store = as_edge_store(edge_source)
+            stream = EdgeChunkStream(store, n, cfg.chunk_size)
+            sstats = StreamStats(chunk_size=stream.chunk_size)
+            pxy_ext = jnp.asarray(
+                np.concatenate([np.stack([px, py], 1), [[0.0, 0.0]]]).astype(
+                    np.float32
+                )
             )
-        )
-        groups_ext = jnp.asarray(np.concatenate([groups, [0]]).astype(np.int32))
-        acc = jnp.zeros(n_groups * hs * ws, jnp.int32)
-        cs = stream.chunk_size
-        weights = (
-            None if edge_weights is None else np.asarray(edge_weights)
-        )
+            groups_ext = jnp.asarray(
+                np.concatenate([groups, [0]]).astype(np.int32))
+            acc = jnp.zeros(n_groups * hs * ws, jnp.int32)
+            cs = stream.chunk_size
+            weights = (
+                None if edge_weights is None else np.asarray(edge_weights)
+            )
         t0 = time.perf_counter()
         with tr.span("render.edges", chunk_size=cs, samples=cfg.edge_samples):
             for i, chunk in enumerate(
-                stream.device_chunks(prefetch=cfg.prefetch, stats=sstats)
+                stream.device_chunks(prefetch=cfg.prefetch, stats=sstats,
+                                     tracer=tr)
             ):
                 winc = None
                 if weights is not None:
@@ -512,8 +515,10 @@ def render(
     edge_source = None
     weights = None
     if cfg.draw_edges and sg is not None:
-        edge_source = np.asarray(sg.edges)
-        weights = np.asarray(sg.weights)
+        tr = cfg.obs if cfg.obs is not None else get_tracer()
+        with tr.span("render.fetch"):  # device → host copy of the superedges
+            edge_source = np.asarray(sg.edges)
+            weights = np.asarray(sg.weights)
     image, stats = render_arrays(
         result.positions, radii, result.groups,
         edge_source, edge_weights=weights, cfg=cfg,
@@ -521,7 +526,7 @@ def render(
     if path is not None:
         from repro.render.png import write_png
 
-        write_png(path, image)
+        write_png(path, image, tracer=cfg.obs)
     return image, stats
 
 

@@ -55,6 +55,61 @@ def test_disabled_tracer_is_noop():
     assert tr.spans() == []
 
 
+def _profiled_host_events(tmp_path, body) -> dict:
+    """Run ``body`` under a ``jax.profiler`` trace; return the host events
+    of the trace read back with ``ProfileData``, as name → [(start, end)]."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return events
+
+
+def test_enabled_spans_land_in_the_profiler_trace(tmp_path):
+    tr = Tracer()
+
+    def body():
+        with tr.span("obs.outer", chunk=3):
+            with tr.span("obs.inner"):
+                time.sleep(0.002)
+
+    events = _profiled_host_events(tmp_path, body)
+    # Named by the span alone: attributes stay in the tracer's record.
+    ((o0, o1),) = events["obs.outer"]
+    ((i0, i1),) = events["obs.inner"]
+    assert o0 <= i0 < i1 <= o1
+    assert i1 - i0 >= 2e6  # the sleep, on the profiler's ns clock
+    assert {s.name for s in tr.spans()} == {"obs.outer", "obs.inner"}
+
+
+def test_disabled_tracer_writes_no_profiler_event(tmp_path):
+    tr = Tracer(enabled=False)
+
+    def body():
+        h = tr.span("obs.disabled", chunk=1)
+        assert h is NULL_SPAN
+        with h:
+            time.sleep(0.001)
+
+    events = _profiled_host_events(tmp_path, body)
+    assert "obs.disabled" not in events
+    assert tr.spans() == []
+
+
 def test_thread_local_span_stacks():
     tr = Tracer()
     err = []
@@ -283,7 +338,9 @@ def test_traced_pipeline_phase_coverage(tmp_path):
     res.render(str(tmp_path / "out.png"))
     names = tr.span_names()
     for phase in ("biggraphvis", "detect", "detect.chunk", "supergraph",
-                  "supergraph.chunk", "layout", "render", "render.compose"):
+                  "supergraph.chunk", "layout", "biggraphvis.fetch", "render",
+                  "render.fetch", "render.edge_setup", "render.compose",
+                  "render.png"):
         assert phase in names, (phase, sorted(names))
     # span tree: biggraphvis is an ancestor of the detect chunks
     spans = tr.spans()
