@@ -17,6 +17,7 @@ from repro.kernels.segment import ops as seg_ops
 from repro.kernels.merge.ref import merge_combine_ref
 from repro.kernels.merge.sorted_merge import merge_combine_pallas
 from repro.kernels.merge import ops as merge_ops
+from repro.obs.metrics import REGISTRY
 from repro.kernels.raster.ref import (
     count_scatter_into_ref,
     count_scatter_ref,
@@ -253,8 +254,11 @@ def _assert_merge_outputs_equal(got, want, label=""):
 
 @pytest.mark.parametrize("cap,c,ks,kc,tn,blk", [
     (64, 16, 20, 10, 64, 64),
-    (256, 64, 200, 64, 64, 128),
+    (256, 64, 200, 64, 64, 128),  # blk = 2·tn
     (100, 24, 77, 20, 32, 64),  # cap/C not tile-aligned: exercises padding
+    (256, 64, 200, 64, 128, 64),  # tn = 2·blk: a band of three blocks
+    (128, 64, 128, 60, 32, 16),  # state full to a block boundary, overflows
+    (128, 64, 100, 0, 64, 32),  # chunk all sentinel
 ])
 def test_merge_kernel_vs_ref(cap, c, ks, kc, tn, blk):
     rng = np.random.default_rng(cap + c)
@@ -280,16 +284,20 @@ def test_merge_kernel_vs_ref(cap, c, ks, kc, tn, blk):
     assert got_pairs == kept
 
 
+@pytest.mark.parametrize("tn,blk", [(32, 32), (16, 8), (8, 16)])
 @pytest.mark.parametrize("case", [
     "empty_chunk", "all_duplicate", "all_padding_state_too",
     "state_at_capacity", "chunk_below_state", "chunk_above_state",
+    "dup_on_tile_boundary", "state_overflows_cap",
 ])
-def test_merge_kernel_adversarial(case):
-    """Pallas-interpret vs ref on the contract's edge cases."""
+def test_merge_kernel_adversarial(case, tn, blk):
+    """Pallas-interpret vs ref on the contract's edge cases, over band
+    shapes (tn = blk, tn = 2·blk, blk = 2·tn). Both runs end on a block
+    boundary, so the last tiles' bands clamp to a run's last block."""
     rng = np.random.default_rng(7)
     s_cap, cap, c = 16, 32, 16
     state = _rand_pairs(rng, 12, s_cap)
-    if case == "empty_chunk":
+    if case == "empty_chunk":  # chunk all sentinel
         chunk = {}
     elif case == "all_duplicate":
         chunk = {p: 1.0 for p in list(state)[:c]}  # every pair already held
@@ -301,14 +309,26 @@ def test_merge_kernel_adversarial(case):
     elif case == "chunk_below_state":
         state = {(8, j): 1.0 for j in range(9, 16)}
         chunk = {(0, j): 2.0 for j in range(1, 8)}  # all keys sort first
-    else:  # chunk_above_state
+    elif case == "chunk_above_state":
         state = {(0, j): 1.0 for j in range(1, 8)}
         chunk = {(8, j): 2.0 for j in range(9, 16)}
+    elif case == "dup_on_tile_boundary":
+        # Chunk keys repeating the state's pairs on either side of a tile
+        # boundary, plus a few new keys.
+        state = _rand_pairs(rng, 24, s_cap)
+        held = sorted(state)
+        edge = tn if tn < len(held) else len(held) // 2
+        chunk = {held[edge - 1]: 3.0, held[edge]: 4.0}
+        chunk.update({p: 2.0 for p in _rand_pairs(rng, 6, s_cap)
+                      if p not in state})
+    else:  # state_overflows_cap: chunk keys below push the state past cap
+        state = {(a, b): 1.0 for a in (4, 5, 6) for b in range(a + 1, 16)}
+        chunk = {(0, j): 2.0 for j in range(1, 16)}
     sa, sb, sw = _sorted_run(state, cap, s_cap)
     ca, cb, cw = _sorted_run(chunk, c, s_cap)
     want = merge_combine_ref(sa, sb, sw, ca, cb, cw, s_cap)
     got = merge_combine_pallas(
-        sa, sb, sw, ca, cb, cw, s_cap, tn=32, blk=32, interpret=True
+        sa, sb, sw, ca, cb, cw, s_cap, tn=tn, blk=blk, interpret=True
     )
     _assert_merge_outputs_equal(got, want, case)
     kept, n = _merge_oracle(state, chunk, cap)
@@ -317,6 +337,29 @@ def test_merge_kernel_adversarial(case):
     assert ((oa < s_cap) == (np.arange(cap) < len(kept))).all()
     want_w = np.array([w for _, w in sorted(kept.items())], np.float32)
     np.testing.assert_array_equal(ow[: len(kept)], want_w)
+
+
+def test_merge_grid_is_banded():
+    """The kernel's grid visits a band of input blocks per output tile:
+    at most 2·band·tiles steps, where a dense (tiles × blocks) grid
+    would take tiles · (cap + C) / blk."""
+    rng = np.random.default_rng(11)
+    s_cap, cap, c, tn, blk = 64, 352, 96, 32, 16
+    sa, sb, sw = _sorted_run(_rand_pairs(rng, 300, s_cap), cap, s_cap)
+    ca, cb, cw = _sorted_run(_rand_pairs(rng, 70, s_cap), c, s_cap)
+    for name in ("merge.grid_steps", "merge.dense_grid_steps"):
+        REGISTRY.gauge(name).set(-1)
+    got = merge_combine_pallas(
+        sa, sb, sw, ca, cb, cw, s_cap, tn=tn, blk=blk, interpret=True
+    )
+    want = merge_combine_ref(sa, sb, sw, ca, cb, cw, s_cap)
+    _assert_merge_outputs_equal(got, want)
+    tiles, band = cap // tn, tn // blk + 1
+    steps = REGISTRY.value("merge.grid_steps")
+    dense = REGISTRY.value("merge.dense_grid_steps")
+    assert 0 < steps <= 2 * band * tiles
+    assert dense == tiles * (cap + c) // blk
+    assert steps * 4 < dense
 
 
 def test_merge_ops_wrapper():

@@ -35,6 +35,7 @@ BERKSTAN_CMS_COLS = 6_649  # default_cms_cols(6.65M edges)
 LIVEJOURNAL_CMS_COLS = 34_681  # default_cms_cols(34.68M edges)
 MAX_SUPER_EDGES = 262_144  # default_config's max_super_edges cap
 SMOKE_SUPER_EDGES = 1 << 20  # chip_smoke.py's capacity for this graph
+WEBGOOGLE_SUPER_EDGES = 1 << 21  # the web-Google benchmark's capacity
 CHUNK = 1 << 20  # edges per streamed chunk in the chip smoke run
 N_GROUPS = 12  # len(PALETTE): render accumulation channels
 
@@ -131,10 +132,11 @@ def test_grid_near_field_compiles(one_chip):
     (MAX_SUPER_EDGES, CHUNK),
     (MAX_SUPER_EDGES, 1 << 14),
     (SMOKE_SUPER_EDGES, CHUNK),
+    (WEBGOOGLE_SUPER_EDGES, CHUNK),
 ])
 def test_merge_combine_compiles(one_chip, cap, c):
-    """Superedge state (default and chip-smoke capacity) + one deduped
-    chunk run."""
+    """Superedge state (default, BerkStan's and web-Google's capacity) +
+    one deduped chunk run."""
     compiled = merge_combine_pallas.lower(
         _sds((cap,), "int32", one_chip),
         _sds((cap,), "int32", one_chip),
