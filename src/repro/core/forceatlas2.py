@@ -608,6 +608,30 @@ def _sharded_fallback_reason(n: int, cfg: FA2Config, mesh) -> str | None:
     return None
 
 
+def sharded_layout_devices(n: int, cfg: FA2Config, mesh) -> int:
+    """Devices ``layout_sharded`` runs the force pass of an ``n``-node
+    layout on: the mesh size where the node partition engages, 1 where it
+    falls back (or ``mesh`` is None)."""
+    if mesh is None or _sharded_fallback_reason(n, cfg, mesh) is not None:
+        return 1
+    return mesh.size
+
+
+def sharded_layout_collective_bytes(n: int, cfg: FA2Config, devices: int) -> int:
+    """Bytes one device contributes to the all-gathers of an ``n``-node
+    ``layout_sharded`` run over ``devices``: per scan step its n/D rows of
+    the force array, and for "grid" of the float32 sorted-order forces too
+    (an upper bound under the adaptive stop, whose frozen steps gather
+    nothing)."""
+    if devices <= 1:
+        return 0
+    row_values = n // devices * 2
+    per_step = row_values * jnp.dtype(cfg.dtype).itemsize
+    if cfg.repulsion == "grid":
+        per_step += row_values * 4
+    return cfg.iterations * per_step
+
+
 @functools.lru_cache(maxsize=None)
 def _sharded_layout_fn(mesh, cfg: FA2Config, n: int):
     from jax.sharding import PartitionSpec as P
@@ -752,6 +776,7 @@ def layout_sharded(
     float32-pinned, so honoring ``cfg.dtype`` sharded is impossible; the
     single-device path keeps its cast-in/cast-out semantics). ``mesh=None``
     falls back silently — that is the caller opting out, not a surprise.
+    ``sharded_layout_devices`` says which of the two a call runs.
     Bit-identical to ``layout`` for "exact" on any platform and for
     "grid" on CPU (on TPU, ``layout``'s "grid" runs Pallas grid kernels
     this path does not mirror), including the adaptive stop: the

@@ -183,7 +183,7 @@ def _block(fn, *args):
 
 def layout_supergraph(
     sg: Supergraph, cfg: BGVConfig, mesh=None, shard_layout: bool = False,
-    tracer=None,
+    tracer=None, stats: StreamStats | None = None,
 ) -> tuple[jnp.ndarray, int]:
     """ForceAtlas2 on the (small, device-resident) supergraph.
 
@@ -199,7 +199,8 @@ def layout_supergraph(
     With ``mesh`` + ``shard_layout`` the force pass is node-partitioned
     over the mesh (``fa2.layout_sharded`` — bit-identical, with its own
     fallbacks). ``s_layout`` is a power of two ≥ 64, so it divides by any
-    power-of-two device count.
+    power-of-two device count. The ``layout.supergraph`` span and, given
+    ``stats``, its "layout" stage record the devices the pass ran on.
     """
     tr = tracer if tracer is not None else get_tracer()
     s_live = max(int(sg.n_supernodes), 2)
@@ -212,19 +213,28 @@ def layout_supergraph(
     )
     mass = jnp.where(jnp.arange(s_layout) < sg.n_supernodes, mass, 0.0)
     sedges = jnp.minimum(sg.edges[:e_layout], s_layout)  # trash → s_layout
-    if mesh is not None and shard_layout:
+    asked = mesh is not None and shard_layout
+    if asked:
         def run(e, w, m):
             return fa2.layout_sharded(e, w, m, s_layout, cfg.layout, mesh)
     else:
         def run(e, w, m):
             return fa2.layout(e, w, m, s_layout, cfg.layout)
+    devices = fa2.sharded_layout_devices(s_layout, cfg.layout, mesh) if asked else 1
     with tr.span(
         "layout.supergraph", n=s_layout, edges=e_layout,
-        sharded=bool(mesh is not None and shard_layout),
+        sharded=devices > 1, devices=devices,
     ):
         pos_live, _trace, iters_run = _block(
             run, sedges, sg.weights[:e_layout], mass
         )
+    if stats is not None:
+        stats.record_stage(
+            "layout", devices,
+            fa2.sharded_layout_collective_bytes(s_layout, cfg.layout, devices),
+            sharded=asked,
+        )
+        stats.publish_shards()
     if cfg.layout.nan_guard:
         # Host sync on the trace is only paid when the sentinel is armed.
         recovered = fa2.recovery_count(_trace)
@@ -296,7 +306,7 @@ def biggraphvis(
                 sg, cfg,
                 mesh=stream.mesh if stream is not None else None,
                 shard_layout=stream.shard_layout if stream is not None else False,
-                tracer=tr,
+                tracer=tr, stats=stats,
             )
         t["layout_s"] = time.perf_counter() - t0
         t["layout_iterations"] = layout_iters

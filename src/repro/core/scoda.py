@@ -290,6 +290,23 @@ def sharded_scoda_update(mesh, cfg: ScodaConfig):
     return jax.jit(mapped, donate_argnums=(0,))
 
 
+def sharded_update_collective_bytes(state, blocks, devices: int,
+                                    cfg: ScodaConfig) -> int:
+    """Bytes one device contributes to the collectives of one
+    ``sharded_scoda_update`` call on ``blocks`` [n_blocks, block_size, 2]
+    over ``devices``: per block, the all-gather of its block_size/D slice
+    (exact block degrees), and the all-reduces of the node-length winner
+    degrees (``max_degree``), candidates and degree increments."""
+    com, deg = state
+    n_blocks, bs, width = blocks.shape
+    per_block = com.nbytes + deg.nbytes  # pmin of candidates, psum of degrees
+    if cfg.conflict == "max_degree":
+        per_block += com.nbytes  # pmax of winner degrees
+    if cfg.degree_update == "scoda" and cfg.exact_block_degrees:
+        per_block += bs // devices * width * blocks.dtype.itemsize
+    return n_blocks * per_block
+
+
 def _scoda_finalize_body(state, n_nodes: int, cfg: ScodaConfig):
     com, deg = state
     if cfg.compress_labels:

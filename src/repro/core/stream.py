@@ -63,6 +63,7 @@ from repro.core.scoda import (
     scoda_init,
     scoda_update,
     sharded_scoda_update,
+    sharded_update_collective_bytes,
 )
 from repro.core.supergraph import (
     Supergraph,
@@ -70,6 +71,7 @@ from repro.core.supergraph import (
     agg_init,
     agg_update,
     community_sizes,
+    sharded_agg_collective_bytes,
     sharded_agg_update,
 )
 from repro.data.edge_store import EDGE_DTYPE, InMemoryEdgeStore, as_edge_store
@@ -139,12 +141,19 @@ class StreamStats:
     pass (repro/render/raster.py, populated under
     ``RenderConfig.time_raster``; benchmarks/render_bench.py).
 
-    ``devices`` is the mesh size the sharded passes actually ran on (1 =
-    unsharded); ``peak_local_bytes`` is the analytic *per-device* resident
-    footprint — replicated state at full size plus this device's 1/D slice
-    of the chunk buffers. With ``devices == 1`` it equals
-    ``peak_device_bytes``; benchmarks/shard_bench.py asserts it shrinks
-    toward 1/D of the single-device peak as the chunk term dominates."""
+    ``stage_devices`` maps each stage that ran ("detect", "supergraph",
+    and "layout" once ``biggraphvis`` has laid out) to the mesh size its
+    compiled update actually ran on, 1 where it ran unsharded or fell
+    back; ``collective_bytes`` maps it to the bytes one device contributed
+    to all-reduces and all-gathers, computed from shapes at dispatch.
+    ``devices`` is the fewest devices any stage that was asked to shard
+    (``sharded_stages``) ran on, so one stage's fallback shows even where
+    the others engaged; 1 when no stage was asked. ``peak_local_bytes`` is
+    the analytic *per-device* resident footprint — replicated state at
+    full size plus this device's 1/D slice of the chunk buffers. With
+    ``devices == 1`` it equals ``peak_device_bytes``;
+    benchmarks/shard_bench.py asserts it shrinks toward 1/D of the
+    single-device peak as the chunk term dominates."""
 
     passes: int = 0
     chunks: int = 0
@@ -160,6 +169,9 @@ class StreamStats:
     raster_update_s: float = 0.0
     raster_chunks: int = 0
     stage_seconds: dict = field(default_factory=dict)
+    stage_devices: dict = field(default_factory=dict)
+    collective_bytes: dict = field(default_factory=dict)
+    sharded_stages: list = field(default_factory=list)
     # Resilience accounting (ISSUE 10): validation/quarantine tallies are
     # copied from the stream's ``ValidationAccounting``. ``quarantined_*``
     # report *distinct* chunks (a permanently-bad chunk is hit once per
@@ -195,7 +207,6 @@ class StreamStats:
             ("stream.seconds", self.seconds),
             ("stream.edges_per_s", self.edges_per_s),
             ("stream.chunk_size", self.chunk_size),
-            ("stream.devices", self.devices),
             ("stream.host_fill_s", self.host_fill_s),
             ("stream.copy_stall_s", self.copy_stall_s),
             ("stream.raster_update_s", self.raster_update_s),
@@ -206,6 +217,27 @@ class StreamStats:
         reg.gauge("stream.peak_device_bytes").set_max(self.peak_device_bytes)
         reg.gauge("stream.peak_local_bytes").set_max(self.peak_local_bytes)
         reg.gauge("stream.peak_host_bytes").set_max(self.peak_host_bytes)
+        self.publish_shards(reg)
+
+    def record_stage(self, stage: str, devices: int, collective_bytes: int,
+                     sharded: bool) -> None:
+        """Record what ``stage`` ran on; ``sharded``: it was asked to shard."""
+        self.stage_devices[stage] = devices
+        self.collective_bytes[stage] = collective_bytes
+        if sharded and stage not in self.sharded_stages:
+            self.sharded_stages.append(stage)
+        self.devices = min(
+            (self.stage_devices[s] for s in self.sharded_stages), default=1)
+
+    def publish_shards(self, registry=None) -> None:
+        """``shard.devices.<stage>`` and ``shard.collective_bytes.<stage>``
+        gauges of the recorded stages, and ``stream.devices``."""
+        reg = registry if registry is not None else REGISTRY
+        for stage, d in self.stage_devices.items():
+            reg.gauge(f"shard.devices.{stage}").set(d)
+            reg.gauge(f"shard.collective_bytes.{stage}").set(
+                self.collective_bytes[stage])
+        reg.gauge("stream.devices").set(self.devices)
 
 
 def tree_bytes(*trees) -> int:
@@ -500,7 +532,6 @@ def _effective_mesh(mesh, shard: bool, *divisible: int):
 def _account_pass_peaks(stats, stream, prefetch, *state_trees, devices: int = 1):
     state_b = tree_bytes(*state_trees)
     chunk_b = stream.chunk_bytes * stream.inflight_buffers(prefetch)
-    stats.devices = max(stats.devices, devices)
     stats.peak_device_bytes = max(stats.peak_device_bytes, state_b + chunk_b)
     # Per-device analytic: state replicated, chunk buffers sharded 1/D.
     stats.peak_local_bytes = max(
@@ -558,6 +589,7 @@ def stream_detect(
         upd, deg_upd = None, _degree_update
     state = scoda_init(n_nodes)
     gdeg = jnp.zeros(n_nodes + 1, dtype=jnp.int32)
+    sent = 0  # bytes this device contributes to collectives
     start_round, start_chunk = 0, 0
     if resume is not None:
         start_round, start_chunk = int(resume["round"]), int(resume["chunk"])
@@ -577,6 +609,11 @@ def stream_detect(
                     start=c0,
                 ):
                     with tr.span("detect.chunk", round=r, chunk=i):
+                        if m is not None:
+                            sent += sharded_update_collective_bytes(
+                                state, chunk, m.size, cfg)
+                            if r == 0:
+                                sent += gdeg.nbytes  # psum of the degrees
                         if r == 0:
                             gdeg = deg_upd(gdeg, chunk)
                         if m is not None:
@@ -601,10 +638,11 @@ def stream_detect(
                         )
     if stats is not None:
         stats.passes += cfg.rounds - start_round
-        _account_pass_peaks(
-            stats, stream, prefetch, state, gdeg,
-            devices=m.size if m is not None else 1,
-        )
+        devices = m.size if m is not None else 1
+        _account_pass_peaks(stats, stream, prefetch, state, gdeg,
+                            devices=devices)
+        stats.record_stage("detect", devices, sent,
+                           sharded=mesh is not None and shard)
     labels, scoda_deg = scoda_finalize(state, n_nodes, cfg)
     return labels, scoda_deg, gdeg[:n_nodes]
 
@@ -656,6 +694,9 @@ def stream_supergraph(
         agg_backend=agg_backend, devices=m.size if m is not None else 1,
     ):
         labels_dense, n_supernodes = dense_labels(labels, n_nodes)
+        # Bytes this device contributes to collectives: the psum of the
+        # CMS sketch (rows × cols float32), then per chunk below.
+        sent = 4 * cms_cfg.rows * cms_cfg.cols if m is not None else 0
         with tr.span("supergraph.sizes"):
             sizes = community_sizes(
                 labels_dense, node_deg, n_supernodes, s_cap, cms_cfg, mesh=m
@@ -709,6 +750,10 @@ def stream_supergraph(
             start=start_chunk,
         ):
             with tr.span("supergraph.chunk", chunk=i):
+                if m is not None:
+                    sent += sharded_agg_collective_bytes(chunk.shape[0], m.size)
+                    if with_modularity:
+                        sent += tree_bytes(mod)  # psum of the accumulators
                 agg = one_agg(agg, chunk, agg_ext)
                 if with_modularity:
                     mod = mod_upd(mod, chunk, mod_ext)
@@ -723,10 +768,13 @@ def stream_supergraph(
                 )
     if stats is not None:
         stats.passes += 1
+        devices = m.size if m is not None else 1
         _account_pass_peaks(
             stats, stream, prefetch, agg, mod, labels_dense, sizes, node_deg,
-            devices=m.size if m is not None else 1,
+            devices=devices,
         )
+        stats.record_stage("supergraph", devices, sent,
+                           sharded=mesh is not None and shard)
     sedges, sweights, n_superedges = agg_finalize(agg)
     q = modularity_finalize(mod) if with_modularity else None
     sg = Supergraph(

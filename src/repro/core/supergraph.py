@@ -256,6 +256,13 @@ def sharded_agg_update(mesh, s_cap: int, max_super_edges: int,
     return jax.jit(mapped, donate_argnums=(0,))
 
 
+def sharded_agg_collective_bytes(chunk_len: int, devices: int) -> int:
+    """Bytes one device contributes to the collectives of one
+    ``sharded_agg_update`` call: the all-gathers of its C/D-long pair
+    columns (int32 a and b, float32 weights), on either backend."""
+    return 3 * (chunk_len // devices) * 4
+
+
 def agg_finalize(state):
     """(sedges [cap,2], sweights [cap], n_superedges) from aggregation state."""
     a, b, w, n = state

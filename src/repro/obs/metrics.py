@@ -10,8 +10,9 @@ without touching the device or allocating.
 
 ``REGISTRY`` is the process-global instance (module helpers ``counter`` /
 ``gauge`` / ``histogram`` resolve against it). Metric names are
-dot-namespaced by subsystem: ``stream.*``, ``layout.*``, ``render.*``,
-``serve.*``, ``jax.*`` — the glossary lives in README "Observability".
+dot-namespaced by subsystem: ``stream.*``, ``shard.*``, ``layout.*``,
+``render.*``, ``serve.*``, ``jax.*`` — the glossary lives in README
+"Observability".
 """
 from __future__ import annotations
 

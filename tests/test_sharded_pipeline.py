@@ -326,3 +326,101 @@ def test_multi_device_subprocess_bit_identity():
     assert got["sg_edges"] == np.asarray(res.supergraph.edges).tolist()
     assert got["modularity"] == res.modularity
     assert got["positions_bytes"] == np.asarray(res.positions).tobytes().hex()
+
+
+_STAGES_SCRIPT = """
+import json, sys, tempfile
+from dataclasses import replace
+import numpy as np
+import jax
+from repro.core.pipeline import biggraphvis, default_config
+from repro.core.stream import StreamConfig
+from repro.data.edge_store import write_npy
+from repro.graph import mode_degree, planted_partition
+from repro.launch.mesh import make_stream_mesh
+from repro.launch.stream_runner import StreamRunner, StreamRunnerConfig
+from repro.obs import Tracer
+from repro.obs.metrics import REGISTRY
+
+assert jax.device_count() == 4, jax.device_count()
+N, COMMUNITIES, SUPER_EDGES, REPULSION = {n}, {communities}, {super_edges}, {repulsion!r}
+edges, _ = planted_partition(N, COMMUNITIES, 0.3, 0.002, seed=11)
+path = write_npy(tempfile.mkdtemp() + "/edges.npy", edges)
+cfg = default_config(N, len(edges), mode_degree(edges, N), rounds=2,
+                     iterations=5)
+cfg = replace(cfg, scoda=replace(cfg.scoda, block_size=128),
+              layout=replace(cfg.layout, repulsion=REPULSION),
+              max_super_edges=SUPER_EDGES)
+plain = biggraphvis(path, N, cfg, stream=StreamConfig(chunk_size=256))
+tr = Tracer()
+runner = StreamRunner(replace(cfg, obs=tr), StreamRunnerConfig(
+    stream=StreamConfig(chunk_size=256, shard_detect=True, shard_layout=True),
+    shard_chunks=True,
+), mesh=make_stream_mesh(4))
+res = runner.run(path, N)
+(span,) = [s for s in tr.spans() if s.name == "layout.supergraph"]
+stages = ("detect", "supergraph", "layout")
+json.dump({{
+    "same": {{
+        "labels": bool(np.array_equal(plain.labels, res.labels)),
+        "superedges": bool(
+            np.array_equal(np.asarray(plain.supergraph.edges),
+                           np.asarray(res.supergraph.edges))
+            and np.array_equal(np.asarray(plain.supergraph.weights),
+                               np.asarray(res.supergraph.weights))
+            and plain.n_superedges == res.n_superedges),
+        "sizes": bool(np.array_equal(plain.sizes, res.sizes)),
+        "modularity": plain.modularity == res.modularity,
+        "positions": bool(np.array_equal(plain.positions, res.positions)),
+    }},
+    "n_edges": len(edges),
+    "n_superedges": int(res.n_superedges),
+    "stream_devices": res.stream.devices,
+    "stage_devices": res.stream.stage_devices,
+    "gauge_devices": {{s: REGISTRY.value("shard.devices." + s) for s in stages}},
+    "gauge_bytes": {{s: REGISTRY.value("shard.collective_bytes." + s)
+                    for s in stages}},
+    "span": span.attrs,
+}}, sys.stdout)
+"""
+
+
+@pytest.mark.parametrize("repulsion,layout_devices", [("exact", 4),
+                                                      ("grid_pallas", 1)])
+def test_runner_npy_records_stage_devices_subprocess(repulsion, layout_devices):
+    """``StreamRunner`` over a ``.npy`` file with every shard flag on 4 forced
+    host devices, the superedge state (64 slots) filled by a chunk's
+    gathered dedupe: bit-identical to the unsharded run of the same file,
+    and each stage's devices and collective bytes recorded in the
+    ``shard.*`` gauges and ``StreamStats``. ``grid_pallas`` has no sharded
+    layout, so that stage and ``StreamStats.devices`` read 1."""
+    super_edges = 64
+    script = textwrap.dedent(_STAGES_SCRIPT).format(
+        n=N, communities=COMMUNITIES, super_edges=super_edges,
+        repulsion=repulsion)
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr
+    got = __import__("json").loads(out.stdout)
+    assert all(got["same"].values()), got["same"]
+    assert got["n_superedges"] >= super_edges  # the state is full
+    want = {"detect": 4, "supergraph": 4, "layout": layout_devices}
+    assert got["stage_devices"] == want
+    assert got["gauge_devices"] == want
+    assert got["stream_devices"] == layout_devices
+    assert got["span"]["sharded"] is (layout_devices > 1)
+    assert got["span"]["devices"] == layout_devices
+    # Bytes from shapes: 2 rounds over 24 chunks of 2 blocks of 128 edges.
+    n_state, chunks = (N + 1) * 4, -(-got["n_edges"] // 256)
+    per_block = 3 * n_state + 128 // 4 * 2 * 4  # pmax, pmin, psum; the gather
+    assert got["gauge_bytes"]["detect"] == (2 * chunks * 2 * per_block
+                                            + chunks * n_state)
+    sketch, modularity = 4 * 256 * 4, (1 + 2 * (N + 1)) * 4
+    assert got["gauge_bytes"]["supergraph"] == sketch + chunks * (
+        3 * 256 // 4 * 4 + modularity)
+    layout = 5 * 64 // 4 * 2 * 4 if layout_devices > 1 else 0  # s_layout 64
+    assert got["gauge_bytes"]["layout"] == layout
