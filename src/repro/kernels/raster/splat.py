@@ -2,13 +2,27 @@
 
 ``count_scatter_pallas`` — the edge-splat scatter. XLA's gather/scatter is
 the weak spot on TPU, so the wrapper sorts the sample positions once
-(cheap, vectorized) and the kernel reuses the sorted-scatter idiom from
-``kernels/merge``: grid = (output tiles × input blocks); a sorted block's
-positions span one contiguous band of output tiles, so ``pl.when`` skips
-every non-overlapping (tile, block) pair and the per-update work is
-O(rows) mask-reductions instead of O(rows × tiles). Counts accumulate in
-int32 — exact and order-independent, which is what makes the renderer's
-chunked==one-shot contract bit-exact.
+(cheap, vectorized) and the kernel scatters the sorted run a (tile, block)
+pair at a time: each step compares one ``blk`` block of positions with one
+``tn``-slot output tile as [tn, blk] masks, O(rows) mask-reductions instead
+of O(rows × tiles). Counts accumulate in int32 — exact and
+order-independent, which is what makes the renderer's chunked==one-shot
+contract bit-exact.
+
+The grid walks only the pairs that can overlap. After the sort, block b
+spans the tiles from ``tile(pos[b·blk])`` to ``tile(pos[b·blk+blk−1])``,
+and consecutive blocks share at most one tile, so the overlapping pairs lie
+on one staircase, monotone in tile and in block. XLA builds that walk from
+the blocks' first and last positions alone — each tile's range of blocks
+by two ``searchsorted`` calls, at least one step per tile so that every
+tile is seeded from the accumulator — as two int32 step tables
+(``tile_of``, ``block_of``), scalar-prefetched into the index maps. The
+grid is (tiles + blocks,): steps past the walk's end repeat its last pair
+and add nothing. A dense (tiles × blocks) grid would take tiles × blocks
+steps, nearly all of them skipped but each still paid for. The fixed band
+of ``kernels/merge`` (ceil(tn/blk) + 1 blocks a tile) does not apply:
+merge positions are unique, splat positions repeat, so a hot pixel or a
+dense cluster of disks puts any number of blocks into one tile.
 
 ``disk_accum_pallas`` — per-pixel disk coverage as a one-hot matmul (the
 ``kernels/segment`` trick pointed at the image plane): for an image tile
@@ -26,27 +40,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.compat import CompilerParams
+from repro.obs.metrics import REGISTRY
 
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
 
-def _scatter_kernel(acc_ref, pos_ref, w_ref, o_ref, *, tn: int, blk: int):
-    b = pl.program_id(1)
+def _scatter_kernel(tile_ref, block_ref, acc_ref, pos_ref, w_ref, o_ref,
+                    *, tn: int, blk: int):
+    s = pl.program_id(0)
+    t = tile_ref[s]
+    prev = jnp.maximum(s - 1, 0)
+    new_tile = (s == 0) | (tile_ref[prev] != t)
 
-    @pl.when(b == 0)
+    @pl.when(new_tile)
     def _init():
         # Seed each output tile from the carried-in accumulator (aliased
         # to the output buffer, so the combine is in place in HBM).
         o_ref[...] = acc_ref[...]
 
     pos = pos_ref[0, :]  # [blk], sorted within the block
-    base = pl.program_id(0) * tn
-    # Sorted block ⇒ output span is [pos[0], pos[blk-1]]; skip tiles
-    # outside it (same band-skip as kernels/merge).
-    overlap = (pos[blk - 1] >= base) & (pos[0] < base + tn)
+    base = t * tn
+    # Steps past the walk's end repeat its last pair; a tile no block
+    # reaches gets one step whose block misses it.
+    repeat = ~new_tile & (block_ref[prev] == block_ref[s])
+    overlap = ~repeat & (pos[blk - 1] >= base) & (pos[0] < base + tn)
 
     @pl.when(overlap)
     def _scatter():
@@ -56,6 +76,32 @@ def _scatter_kernel(acc_ref, pos_ref, w_ref, o_ref, *, tn: int, blk: int):
         o_ref[0, :] += jnp.sum(
             jnp.where(hit, w_ref[0, :][None, :], 0), axis=1
         )
+
+
+def _walk(pos_p: jnp.ndarray, tiles: int, tn: int, blk: int):
+    """Step tables (tile_of, block_of), each [tiles + blocks] int32, of the
+    monotone walk over the (tile, block) pairs a sorted run can overlap.
+
+    Reads only the blocks' first and last positions. Tile t gets
+    max(1, overlapping blocks) consecutive steps, which sum to at most
+    tiles + blocks − 1; later steps repeat the last pair.
+    """
+    blocks = pos_p.shape[0] // blk
+    bases = jnp.arange(tiles, dtype=jnp.int32) * tn
+    # lo[t]: the first block whose last position reaches tile t; hi[t]: the
+    # first block that starts past it. Blocks lo[t]..hi[t]-1 overlap it.
+    lo = jnp.searchsorted(pos_p[blk - 1 :: blk], bases, side="left")
+    hi = jnp.searchsorted(pos_p[::blk], bases + tn, side="left")
+    lo, hi = lo.astype(jnp.int32), hi.astype(jnp.int32)
+    n_steps = jnp.maximum(hi - lo, 1)
+    ends = jnp.cumsum(n_steps)
+    s = jnp.arange(tiles + blocks, dtype=jnp.int32)
+    tile_of = jnp.minimum(
+        jnp.searchsorted(ends, s, side="right").astype(jnp.int32), tiles - 1
+    )
+    k = jnp.minimum(s - (ends - n_steps)[tile_of], n_steps[tile_of] - 1)
+    block_of = jnp.minimum(lo[tile_of] + k, blocks - 1)
+    return tile_of, block_of
 
 
 @functools.partial(jax.jit, static_argnames=("size", "tn", "blk", "interpret"))
@@ -84,7 +130,7 @@ def count_scatter_pallas(
     n_pad = ((n + blk - 1) // blk) * blk
     size_pad = ((size + tn - 1) // tn) * tn
     # INT32_MAX pad keeps the tail block sorted and outside every tile.
-    pos_p = jnp.pad(pos_s, (0, n_pad - n), constant_values=_INT32_MAX)[None, :]
+    pos_s = jnp.pad(pos_s, (0, n_pad - n), constant_values=_INT32_MAX)
     inc_p = jnp.pad(inc_s, (0, n_pad - n))[None, :]
     # The accumulator is one [1, size_pad] row blocked (1, tn) — the TPU
     # lowering's block rule (as in kernels/merge).
@@ -92,24 +138,28 @@ def count_scatter_pallas(
         acc2d = jnp.zeros((1, size_pad), jnp.int32)
     else:
         acc2d = jnp.pad(acc, (0, size_pad - size))[None, :]
-    grid = (size_pad // tn, n_pad // blk)
+    tiles, blocks = size_pad // tn, n_pad // blk
+    tile_of, block_of = _walk(pos_s, tiles, tn, blk)
+    REGISTRY.gauge("raster.grid_steps").set(tiles + blocks)
+    REGISTRY.gauge("raster.dense_grid_steps").set(tiles * blocks)
+    spec_tile = pl.BlockSpec((1, tn), lambda s, t_of, b_of: (0, t_of[s]))
+    spec_block = pl.BlockSpec((1, blk), lambda s, t_of, b_of: (0, b_of[s]))
     out = pl.pallas_call(
         functools.partial(_scatter_kernel, tn=tn, blk=blk),
         name="raster_count_scatter",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tn), lambda t, b: (0, t)),
-            pl.BlockSpec((1, blk), lambda t, b: (0, b)),
-            pl.BlockSpec((1, blk), lambda t, b: (0, b)),
-        ],
-        out_specs=pl.BlockSpec((1, tn), lambda t, b: (0, t)),
-        out_shape=jax.ShapeDtypeStruct((1, size_pad), jnp.int32),
-        input_output_aliases={0: 0},
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles + blocks,),
+            in_specs=[spec_tile, spec_block, spec_block],
+            out_specs=spec_tile,
         ),
+        out_shape=jax.ShapeDtypeStruct((1, size_pad), jnp.int32),
+        # Operand 2 (after the two step tables) is the accumulator.
+        input_output_aliases={2: 0},
+        # One axis: a tile's steps must stay consecutive.
+        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(acc2d, pos_p, inc_p)
+    )(tile_of, block_of, acc2d, pos_s[None, :], inc_p)
     return out[0, :size]
 
 

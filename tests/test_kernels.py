@@ -424,29 +424,88 @@ def test_count_scatter_kernel_vs_ref(n, size, tn, blk):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("case", ["one_pixel", "all_padding", "negatives"])
+@pytest.mark.parametrize("case", [
+    "one_pixel", "all_padding", "negatives", "hot_tile", "far_tiles", "into_acc",
+])
 def test_count_scatter_adversarial(case):
     """Edge-splat contract edge cases: every sample in one pixel (dense
     single-cell collision), an empty / all-padding chunk (every position
-    is the dropped marker), and negative positions (must drop, not wrap)."""
-    n, size = 640, 256
+    is the dropped marker), negative positions (must drop, not wrap), one
+    tile holding more blocks than a fixed band of ceil(tn/blk) + 1 would
+    visit, samples in three tiles far apart with the tiles between them
+    untouched, and accumulation into a non-zero ``acc``."""
+    n, size, tn, blk = 640, 256, 64, 128
     rng = np.random.default_rng(3)
     inc = rng.integers(1, 4, n).astype(np.int32)
+    acc = None
     if case == "one_pixel":
         pos = np.full(n, 77, np.int32)
     elif case == "all_padding":
         pos = np.full(n, INT32_MAX, np.int32)
-    else:
+    elif case == "negatives":
         pos = rng.integers(-5, size, n).astype(np.int32)
+    elif case == "hot_tile":
+        # 600 samples in tile 1 fill 5 blocks; a fixed band visits 2.
+        pos = np.concatenate([
+            rng.integers(0, tn, 20),
+            rng.integers(tn, 2 * tn, 600),
+            rng.integers(3 * tn, 4 * tn, 20),
+        ]).astype(np.int32)
+        rng.shuffle(pos)
+    elif case == "far_tiles":
+        size = 40 * tn
+        hot = np.array([2, 17, 38])
+        pos = (hot[rng.integers(0, 3, n)] * tn + rng.integers(0, tn, n))
+        pos = pos.astype(np.int32)
+        acc = rng.integers(0, 50, size).astype(np.int32)
+    else:
+        pos = rng.integers(0, size, n).astype(np.int32)
+        acc = rng.integers(0, 50, size).astype(np.int32)
     want = count_scatter_ref(jnp.asarray(pos), jnp.asarray(inc), size)
+    if acc is not None:
+        want = want + jnp.asarray(acc)
     got = count_scatter_pallas(
-        jnp.asarray(pos), jnp.asarray(inc), size, tn=64, blk=128, interpret=True
+        jnp.asarray(pos), jnp.asarray(inc), size,
+        acc=None if acc is None else jnp.asarray(acc),
+        tn=tn, blk=blk, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     if case == "one_pixel":
         assert int(want[77]) == int(inc.sum())
     if case == "all_padding":
         assert int(np.asarray(want).sum()) == 0
+    if case == "hot_tile":
+        in_tile = (pos >= tn) & (pos < 2 * tn)
+        assert int(np.asarray(want)[tn : 2 * tn].sum()) == int(inc[in_tile].sum())
+    if case == "far_tiles":
+        # Untouched tiles carry the accumulator through unchanged.
+        cold = np.ones(size, bool)
+        for t in (2, 17, 38):
+            cold[t * tn : (t + 1) * tn] = False
+        np.testing.assert_array_equal(np.asarray(got)[cold], acc[cold])
+
+
+def test_count_scatter_grid_is_banded():
+    """The kernel's grid walks the (tile, block) pairs sorted samples can
+    overlap: at most tiles + blocks steps, where a dense (tiles × blocks)
+    grid would take their product."""
+    rng = np.random.default_rng(13)
+    n, size, tn, blk = 4096, 4000, 64, 128
+    pos = rng.integers(0, size, n).astype(np.int32)
+    inc = rng.integers(1, 4, n).astype(np.int32)
+    for name in ("raster.grid_steps", "raster.dense_grid_steps"):
+        REGISTRY.gauge(name).set(-1)
+    got = count_scatter_pallas(
+        jnp.asarray(pos), jnp.asarray(inc), size, tn=tn, blk=blk, interpret=True
+    )
+    want = count_scatter_ref(jnp.asarray(pos), jnp.asarray(inc), size)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    tiles, blocks = -(-size // tn), n // blk
+    steps = REGISTRY.value("raster.grid_steps")
+    dense = REGISTRY.value("raster.dense_grid_steps")
+    assert 0 < steps <= tiles + blocks
+    assert dense == tiles * blocks
+    assert steps * 4 < dense
 
 
 def test_count_scatter_into_matches_fresh():

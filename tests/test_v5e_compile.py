@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.coloring import PALETTE
 from repro.kernels.cms.cms_update import cms_update_pallas
 from repro.kernels.grid.tiled import far_field_pallas, near_field_pallas
 from repro.kernels.merge.sorted_merge import merge_combine_pallas
@@ -37,7 +38,9 @@ MAX_SUPER_EDGES = 262_144  # default_config's max_super_edges cap
 SMOKE_SUPER_EDGES = 1 << 20  # chip_smoke.py's capacity for this graph
 WEBGOOGLE_SUPER_EDGES = 1 << 21  # the web-Google benchmark's capacity
 CHUNK = 1 << 20  # edges per streamed chunk in the chip smoke run
-N_GROUPS = 12  # len(PALETTE): render accumulation channels
+N_GROUPS = len(PALETTE)  # render accumulation channels
+SKITTER_SMALL_DISKS = 1 << 16  # as-Skitter's small-disk splat, padded to 2^k
+DISK_SAMPLES = 18 * 18  # render/raster's _BBOX² samples per small disk
 
 
 @pytest.fixture(scope="module")
@@ -149,11 +152,16 @@ def test_merge_combine_compiles(one_chip, cap, c):
     _assert_kernel(compiled)
 
 
-@pytest.mark.parametrize("side", [1024, 256])
-def test_count_scatter_compiles(one_chip, side):
-    """Edge splats: one 64k-edge render chunk × 8 samples into the
-    [12, side, side] accumulator (full image and one served tile)."""
-    n = (1 << 16) * 8
+@pytest.mark.parametrize("n,side", [
+    ((1 << 16) * 8, 1024),
+    ((1 << 16) * 8, 256),
+    (SKITTER_SMALL_DISKS * DISK_SAMPLES, 1024),
+], ids=["1024", "256", "skitter_small_disks"])
+def test_count_scatter_compiles(one_chip, n, side):
+    """Splats into the [len(PALETTE), side, side] accumulator: one 64k-edge
+    render chunk × 8 samples (full image and one served tile), and the
+    largest call the benchmark makes, as-Skitter's small-disk splat, whose
+    scalar-prefetched step tables are the longest."""
     size = N_GROUPS * side * side
     compiled = count_scatter_pallas.lower(
         _sds((n,), "int32", one_chip),
