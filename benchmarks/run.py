@@ -1,5 +1,5 @@
 """Benchmark harness entry point — one module per paper table/figure plus
-the engine benches and the roofline summary. Prints
+the engine benches. Prints
 ``name,us_per_call,derived`` CSV and writes each module's rows as unified
 structured records (benchmarks/common.py schema) to repo-root
 ``BENCH_<name>.json``; ``--list`` prints the registry with each bench's
@@ -47,9 +47,9 @@ def main() -> None:
 
     from benchmarks import (agg_bench, fa2_bench, fig_params, kernels_bench,
                             obs_bench, quality_bench, render_bench,
-                            resilience_bench, roofline, serve_bench,
-                            shard_bench, stream_bench, table1_speedup,
-                            table2_hashes, table3_rounds)
+                            resilience_bench, serve_bench, shard_bench,
+                            stream_bench, table1_speedup, table2_hashes,
+                            table3_rounds)
     from benchmarks.common import record_from_csv, write_bench_json
 
     modules = {
@@ -67,7 +67,6 @@ def main() -> None:
         "shard": shard_bench,
         "obs": obs_bench,
         "resilience": resilience_bench,
-        "roofline": roofline,
     }
     if args.list:
         width = max(map(len, modules))

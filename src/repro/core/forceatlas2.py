@@ -425,12 +425,12 @@ def step(
 ):
     """One FA2 iteration (Algorithm 1 body): forces → speeds → displacement.
 
-    Single-step public API (launch/steps.py builds the distributed layout
-    cell on it): edge scatter runs inside the call. For the grid backends,
-    pass precomputed ``(cell, order)`` from ``kernels/grid.bin_and_sort``
-    to skip the per-call re-bin + argsort — repeated-step callers refresh
-    them every ``cfg.grid_rebuild`` steps, mirroring ``layout``'s scan
-    carry. ``layout`` also hoists the edge sort — prefer it for full runs.
+    Single-step public API: edge scatter runs inside the call. For the
+    grid backends, pass precomputed ``(cell, order)`` from
+    ``kernels/grid.bin_and_sort`` to skip the per-call re-bin + argsort —
+    repeated-step callers refresh them every ``cfg.grid_rebuild`` steps,
+    mirroring ``layout``'s scan carry. ``layout`` also hoists the edge
+    sort — prefer it for full runs.
 
     Returns ``(state, trace_row)`` with the same ``[g_swing, g_traction,
     global_speed]`` row ``layout`` traces per iteration.

@@ -12,10 +12,9 @@ Every edge-consuming stage runs through the streaming chunked-edge engine
 the edge list as one chunk by default and as fixed-size chunks (device
 residency independent of |E|) when given a ``StreamConfig``. The
 multi-device form (edge shards streamed per device; CMS merged by
-all-reduce, labels by all-reduce-min — DESIGN.md §4) is lowered and
-compiled for the production meshes by ``launch/steps.build_bgv_step``
-(the ``biggraphvis`` dry-run cells); ``launch/stream_runner.py`` drives
-the chunked engine with device placement and host prefetch.
+all-reduce, labels by all-reduce-min — DESIGN.md §4) runs when the
+``StreamConfig`` carries a mesh; ``launch/stream_runner.py`` drives the
+chunked engine with device placement and host prefetch.
 """
 from __future__ import annotations
 

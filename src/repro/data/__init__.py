@@ -1,5 +1,5 @@
-"""Data layer: synthetic batch pipelines (``pipeline``) and out-of-core
-edge stores for the streaming engine (``edge_store``).
+"""Data layer: out-of-core edge stores for the streaming engine
+(``edge_store``).
 
 Re-exports from ``edge_store`` are lazy (PEP 562) so running the converter
 CLI as ``python -m repro.data.edge_store`` does not import the module twice.

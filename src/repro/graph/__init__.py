@@ -1,10 +1,7 @@
-"""Graph substrate: generators, IO, padding, degrees, neighbor sampling."""
+"""Graph substrate: generators, padding, degrees."""
 from repro.graph.generators import (
     planted_partition,
     powerlaw_graph,
-    grid_mesh,
-    batched_molecules,
-    erdos_renyi,
 )
 from repro.graph.utils import (
     degrees,
@@ -13,4 +10,13 @@ from repro.graph.utils import (
     pad_to_multiple,
     EDGE_SENTINEL,
 )
-from repro.graph.sampling import NeighborSampler
+
+__all__ = [
+    "EDGE_SENTINEL",
+    "degrees",
+    "mode_degree",
+    "pad_edges",
+    "pad_to_multiple",
+    "planted_partition",
+    "powerlaw_graph",
+]

@@ -16,9 +16,6 @@ import numpy as np
 __all__ = [
     "planted_partition",
     "powerlaw_graph",
-    "erdos_renyi",
-    "grid_mesh",
-    "batched_molecules",
 ]
 
 
@@ -71,15 +68,6 @@ def _pair_from_index(idx: np.ndarray, n: int) -> tuple[np.ndarray, np.ndarray]:
         i = i + bad_hi.astype(np.int64) - bad_lo.astype(np.int64)
     j = idx - row_start + i + 1
     return i, j
-
-
-def erdos_renyi(n: int, p: float, seed: int = 0) -> np.ndarray:
-    """G(n, p) with edges sampled by geometric skipping."""
-    rng = np.random.default_rng(seed)
-    n_pairs = n * (n - 1) // 2
-    idx = _sample_pairs_gnp(rng, n_pairs, p)
-    i, j = _pair_from_index(idx, n)
-    return np.stack([i, j], axis=1).astype(np.int32)
 
 
 def planted_partition(
@@ -144,29 +132,3 @@ def powerlaw_graph(n: int, m: int = 4, seed: int = 0) -> np.ndarray:
     out = np.concatenate(edges).astype(np.int32)
     rng.shuffle(out)
     return out
-
-
-def grid_mesh(nx: int, ny: int) -> np.ndarray:
-    """4-connected grid mesh (MeshGraphNet-style domain). Returns edges [E,2]."""
-    idx = np.arange(nx * ny).reshape(nx, ny)
-    right = np.stack([idx[:, :-1].ravel(), idx[:, 1:].ravel()], axis=1)
-    down = np.stack([idx[:-1, :].ravel(), idx[1:, :].ravel()], axis=1)
-    return np.concatenate([right, down]).astype(np.int32)
-
-
-def batched_molecules(
-    batch: int, n_nodes: int, n_edges: int, d_feat: int, seed: int = 0
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """A batch of random small graphs packed into one disjoint union.
-
-    Returns (edges [batch*n_edges, 2], feats [batch*n_nodes, d_feat],
-    graph_ids [batch*n_nodes]).
-    """
-    rng = np.random.default_rng(seed)
-    src = rng.integers(0, n_nodes, size=(batch, n_edges))
-    dst = (src + 1 + rng.integers(0, n_nodes - 1, size=(batch, n_edges))) % n_nodes
-    offset = (np.arange(batch) * n_nodes)[:, None]
-    edges = np.stack([(src + offset).ravel(), (dst + offset).ravel()], axis=1)
-    feats = rng.standard_normal((batch * n_nodes, d_feat)).astype(np.float32)
-    graph_ids = np.repeat(np.arange(batch), n_nodes).astype(np.int32)
-    return edges.astype(np.int32), feats, graph_ids

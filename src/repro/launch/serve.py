@@ -15,8 +15,7 @@ The start path turns on JAX's persistent compilation cache
 where set, else ``.bgv-compile-cache/`` at the checkout root;
 ``--no-compile-cache`` disables), so a restarted service deserializes its
 compiled render/layout steps instead of recompiling them — cold-start
-compile otherwise dominates first-request latency. The former LM decode demo lives on in
-``examples/serve_lm.py`` (engine: ``repro/serve/engine.py``).
+compile otherwise dominates first-request latency.
 """
 from __future__ import annotations
 

@@ -2,8 +2,7 @@
 
 Owns device placement for the chunk buffers: single-device by default, or
 row-sharded across a mesh's devices (the per-chunk scatter updates then
-merge through XLA's all-reduce — the same collective structure as the
-``bgv_detect`` dry-run cells in launch/steps.py). Transfers are
+merge through XLA's all-reduce). Transfers are
 ``device_put_copied`` (kernels/compat.py), whose result stops reading the
 engine's reusable staging buffers once it is ready, and the engine overlaps them
 with compute via its double-buffered staging ring

@@ -6,11 +6,6 @@ corrupt or flaky input (validation/quarantine — ``validate``), and a way
 to prove both deterministically (fault injection — ``chaos``). Graceful
 degradation in the hot paths lives with the code it guards
 (``core/forceatlas2.FA2Config.nan_guard``, ``serve/tiles.TileEngine``).
-
-``repro.train.checkpoint`` is now a deprecated re-export shim over
-``repro.resilience.checkpoint`` (same format, same functions); the
-training-substrate ``CheckpointManager``/``ElasticPlan`` are re-exported
-here as the step-counted (rather than chunk-boundary) flavor.
 """
 from repro.resilience.checkpoint import (  # noqa: F401
     CheckpointMismatchError,
@@ -36,17 +31,11 @@ from repro.resilience.validate import (  # noqa: F401
     ValidationPolicy,
     validated_read,
 )
-from repro.train.fault_tolerance import (  # noqa: F401
-    CheckpointManager,
-    ElasticPlan,
-)
 
 __all__ = [
-    "CheckpointManager",
     "CheckpointMismatchError",
     "ChaosConfig",
     "ChaosEdgeStore",
-    "ElasticPlan",
     "KillSwitch",
     "Preempted",
     "SimulatedPreemption",

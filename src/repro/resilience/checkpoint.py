@@ -1,11 +1,6 @@
 """Checkpoint save/restore with elastic resharding + the streaming
 checkpointer (ISSUE 10 tentpole).
 
-Promoted from the seed's dormant ``repro/train/checkpoint.py`` (which now
-re-exports this module and is deprecated at its old path): the format and
-atomicity guarantees are unchanged, and the training substrate keeps
-importing through the shim.
-
 Format: one .npz per checkpoint (flattened pytree with '/'-joined path
 keys) + a meta.json (step, phase/round/chunk cursor, config fingerprint).
 Writes are atomic (tmp + rename) and a keep-last-k window is enforced —

@@ -1,18 +1,14 @@
 """Serving subsystem: the interactive tile-pyramid layout service
 (``tiles`` — pan/zoom over a computed ``BGVResult`` with an LRU tile
-cache and slot-batched re-renders) and the continuous-batching LM decode
-engine the tick design came from (``engine``).
+cache and slot-batched re-renders).
 
-Imports are lazy (PEP 562): ``repro.serve.TileEngine`` pulls only the
-tile service; the LM engine's transformer stack loads only when asked
-for.
+Imports are lazy (PEP 562): importing ``repro.serve`` loads nothing until
+an export is asked for.
 """
 import importlib
 
 _EXPORTS = {
     "DrillSpec": "repro.serve.tiles",
-    "LMEngine": "repro.serve.engine",
-    "Request": "repro.serve.engine",
     "TileCache": "repro.serve.tiles",
     "TileConfig": "repro.serve.tiles",
     "TileEngine": "repro.serve.tiles",

@@ -18,11 +18,10 @@ and zoom. This module turns a finished layout into a cacheable surface
   structure: the induced subgraph of its member nodes is laid out and
   recolored by ``full_layout_colored`` (sub-communities re-detected
   inside the community) and rendered to a fixed-size tile.
-* ``TileEngine`` — the serving loop, modeled on ``serve/engine.py``'s
-  batched-tick design: requests are served from a byte-capped LRU
-  ``TileCache`` on hit, and queued misses are rendered in slot-capped
-  batches per ``tick()`` (fixed tile shapes keep every tick on already
-  compiled code).
+* ``TileEngine`` — the serving loop, a continuous-batching tick design:
+  requests are served from a byte-capped LRU ``TileCache`` on hit, and
+  queued misses are rendered in slot-capped batches per ``tick()``
+  (fixed tile shapes keep every tick on already compiled code).
 * ``synthetic_trace`` — the zipfian pan/zoom traffic model shared by
   ``benchmarks/serve_bench.py`` and ``launch/serve.py``.
 
@@ -398,9 +397,9 @@ class TileRequest:
 class TileEngine:
     """Pan/zoom tile server: LRU cache in front of slot-batched re-renders.
 
-    Mirrors ``serve/engine.py``'s continuous-batching shape: ``submit``
-    attaches a request (cache hits complete immediately), ``tick`` takes
-    up to ``slots`` *distinct* queued tile addresses, renders them — every
+    Continuous-batching shape: ``submit`` attaches a request (cache hits
+    complete immediately), ``tick`` takes up to ``slots`` *distinct*
+    queued tile addresses, renders them — every
     render hits the already-compiled fixed-shape jit entries, so ticks
     never recompile in steady state — and completes all requests waiting
     on those tiles (duplicates collapse into one render).

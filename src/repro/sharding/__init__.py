@@ -1,10 +1,7 @@
 from repro.sharding.rules import (
-    PROFILES,
-    spec_for,
-    filter_spec,
-    params_shardings,
-    batch_sharding,
     row_chunk_spec,
     block_chunk_spec,
     linear_axis_index,
 )
+
+__all__ = ["block_chunk_spec", "linear_axis_index", "row_chunk_spec"]

@@ -1,117 +1,16 @@
-"""Logical-axis → mesh-axis rules (t5x/MaxText style).
+"""Stream-chunk placements for the sharded streaming engine (core/stream.py,
+core/scoda.py, launch/stream_runner.py).
 
-Every ParamSpec carries logical axis names; a *profile* is an ordered map
-logical-axis → mesh-axis (or tuple of mesh axes). ``spec_for`` resolves one
-param: each dimension takes its mapped mesh axis unless (a) the axis is
-already used by an earlier dimension of the same param, or (b) the dim
-size is not divisible by the mesh-axis extent (XLA requires divisibility —
-verified empirically, DESIGN.md §8). Rules therefore degrade gracefully:
-granite's per-expert d_ff=512 simply stays unsharded after "expert" takes
-the model axis.
-
-Profiles (DESIGN.md §4):
-  tp      — Megatron TP over "model" (heads/mlp/vocab/expert) + ZeRO-3-style
-            param sharding of the d_model ("embed") dim over "data".
-  fsdp    — gemma3 (8 heads < 16): weights sharded on embed→model and
-            mlp/vocab→data; attention heads replicated.
-  gnn     — replicated (small) params; nodes/edges sharded over all axes.
-  recsys  — item table sharded on vocab→model; everything else replicated.
+Chunks are edge buffers, so they shard over EVERY mesh axis: a chunk row
+belongs to exactly one device. ``row_chunk_spec`` places rows for the
+supergraph/degree/modularity passes, ``block_chunk_spec`` the within-block
+axis for the detect pass, and ``linear_axis_index`` recovers a device's
+row order inside a ``shard_map`` body.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.models.param import is_spec
-
-PROFILES: dict[str, dict[str, tuple[str, ...]]] = {
-    "tp": {
-        "heads": ("model",),
-        "expert": ("model",),
-        "mlp": ("model",),
-        "vocab": ("model",),
-        # ZeRO-3-style param sharding of d_model over every DP axis: on the
-        # multi-pod mesh this is 32-way (pod×data) — the 1T cells need it.
-        "embed": ("pod", "data"),
-    },
-    "fsdp": {
-        "embed": ("model",),
-        "mlp": ("data",),
-        "vocab": ("data",),
-    },
-    "gnn": {},
-    "recsys": {
-        "vocab": ("model",),
-    },
-}
-
-
-def filter_spec(spec: P, mesh: Mesh) -> P:
-    """Drop mesh axes that don't exist on this mesh (e.g. "pod" on single-pod)."""
-    names = set(mesh.axis_names)
-    out = []
-    for entry in spec:
-        if entry is None:
-            out.append(None)
-        elif isinstance(entry, (tuple, list)):
-            kept = tuple(a for a in entry if a in names)
-            out.append(kept if kept else None)
-        else:
-            out.append(entry if entry in names else None)
-    return P(*out)
-
-
-def spec_for(shape, axes, profile: dict, mesh: Mesh) -> P:
-    used: set[str] = set()
-    out = []
-    for dim, ax in zip(shape, axes):
-        mapped = profile.get(ax)
-        if mapped is None:
-            out.append(None)
-            continue
-        cand = tuple(a for a in mapped if a in mesh.axis_names and a not in used)
-        extent = 1
-        for a in cand:
-            extent *= mesh.shape[a]
-        if cand and extent > 1 and dim % extent == 0:
-            out.append(cand if len(cand) > 1 else cand[0])
-            used.update(cand)
-        else:
-            out.append(None)
-    return P(*out)
-
-
-def params_shardings(specs, profile_name: str, mesh: Mesh):
-    """ParamSpec tree → NamedSharding tree."""
-    profile = PROFILES[profile_name]
-
-    def one(s):
-        return NamedSharding(mesh, spec_for(s.shape, s.logical_axes, profile, mesh))
-
-    return jax.tree_util.tree_map(one, specs, is_leaf=is_spec)
-
-
-def shardings_for_axes(abstract_tree, axes_tree, profile_name: str, mesh: Mesh):
-    """Same resolution for arbitrary (ShapeDtypeStruct, logical-axes) trees —
-    used for optimizer state."""
-    profile = PROFILES[profile_name]
-
-    def one(a, ax):
-        return NamedSharding(mesh, spec_for(a.shape, ax, profile, mesh))
-
-    return jax.tree_util.tree_map(one, abstract_tree, axes_tree)
-
-
-def batch_sharding(mesh: Mesh, *specs: P):
-    """Helper: NamedShardings for batch pytrees, filtering missing axes."""
-    return tuple(NamedSharding(mesh, filter_spec(s, mesh)) for s in specs)
-
-
-# --------------------------------------------------------------------------
-# Stream-chunk placements (core/stream.py sharded engine, launch/
-# stream_runner.py). Chunks are edge buffers, not params, so they shard
-# over EVERY mesh axis: a chunk row belongs to exactly one device.
-# --------------------------------------------------------------------------
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def row_chunk_spec(mesh: Mesh) -> P:

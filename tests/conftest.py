@@ -3,7 +3,7 @@ import sys
 
 # Tests are documented to run with PYTHONPATH=src; this makes them robust
 # without it. Do NOT set XLA_FLAGS here — smoke tests must see 1 device;
-# only launch/dryrun.py forces 512 host devices (and runs out-of-process).
+# the sharded tests force their device count in a subprocess.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # The property tests use hypothesis (a dev-extra dependency — CI installs it

@@ -277,48 +277,6 @@ def test_step_precomputed_cell_order_parity():
     assert np.array_equal(np.asarray(r1), np.asarray(r2))
 
 
-def test_bgv_layout_cell_threads_grid_binning():
-    """The dry-run bgv_layout cell for grid backends takes (cell, order)
-    operands and matches a direct fa2.step with the same precomputed
-    binning."""
-    from repro.configs.base import ArchConfig, ShapeSpec
-    from repro.configs.biggraphvis import BGVDryConfig
-    from repro.kernels.grid import ops as grid_ops
-    from repro.launch.mesh import make_host_mesh
-    from repro.launch.steps import build_bgv_step
-
-    n, e = 128, 256
-    shape = ShapeSpec("t", "bgv_layout", n_nodes=n, n_edges=e)
-    mesh = make_host_mesh()
-    exact = build_bgv_step(
-        ArchConfig("t", "bgv", "gnn", BGVDryConfig()), shape, mesh)
-    grid = build_bgv_step(
-        ArchConfig("t", "bgv", "gnn",
-                   BGVDryConfig(layout_repulsion="grid", layout_grid_size=8,
-                                layout_grid_window=8)),
-        shape, mesh)
-    assert len(grid.abstract_args) == len(exact.abstract_args) + 2
-    for extra in grid.abstract_args[-2:]:
-        assert extra.shape == (n,) and extra.dtype == jnp.int32
-
-    rng = np.random.default_rng(3)
-    pos = jnp.asarray(rng.uniform(-300, 300, (n, 2)).astype(np.float32))
-    prev_f = jnp.zeros_like(pos)
-    mass = jnp.asarray(rng.uniform(1, 4, n).astype(np.float32))
-    radii = jnp.sqrt(mass)
-    edges = jnp.asarray(rng.integers(0, n, (e, 2)).astype(np.int32))
-    w = jnp.ones(e, jnp.float32)
-    cell, order = grid_ops.bin_and_sort(pos, 8)
-    got_pos, got_f = grid.fn(pos, prev_f, mass, radii, edges, w, cell, order)
-    cfg = fa2.FA2Config(iterations=1, use_radii=True, repulsion="grid",
-                        grid_size=8, grid_window=8)
-    (want_pos, want_f, _), _ = fa2.step(
-        (pos, prev_f, jnp.float32(1.0)), edges, w, mass, radii, cfg, n,
-        cell=cell, order=order)
-    assert np.array_equal(np.asarray(got_pos), np.asarray(want_pos))
-    assert np.array_equal(np.asarray(got_f), np.asarray(want_f))
-
-
 # --------------------------------------------------------- quality metrics
 
 def _path_graph(n=50):

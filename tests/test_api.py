@@ -1,6 +1,7 @@
 """Public API surface: top-level exports, signature snapshots, the
 render-kwarg deprecation shims, and the default_config CMS-width formula
 (pinning the docstring/code reconciliation)."""
+import importlib
 import inspect
 import warnings
 
@@ -30,11 +31,15 @@ def test_stable_exports_in_all():
     assert set(STABLE_EXPORTS) <= set(repro.__all__)
 
 
-def test_all_exports_resolve():
-    for name in repro.__all__:
-        assert getattr(repro, name) is not None
-    for name in repro.serve.__all__:
-        assert getattr(repro.serve, name) is not None
+@pytest.mark.parametrize(
+    "module",
+    ["repro", "repro.serve", "repro.resilience", "repro.sharding", "repro.graph"],
+)
+def test_all_exports_resolve(module):
+    mod = importlib.import_module(module)
+    assert mod.__all__
+    for name in mod.__all__:
+        assert getattr(mod, name) is not None
 
 
 def test_dir_includes_exports():

@@ -167,21 +167,6 @@ def test_checkpointer_seq_seeded_on_restore(tmp_path):
     assert latest_step(d) == 6  # not pruned-on-arrival under steps 3..5
 
 
-def test_train_checkpoint_shim_reexports():
-    """The deprecated old path must expose the same objects (same format,
-    same functions) so existing imports keep working."""
-    from repro.resilience import checkpoint as new
-    from repro.train import checkpoint as old
-    from repro.train.fault_tolerance import CheckpointManager
-
-    assert old.save is new.save
-    assert old.restore is new.restore
-    assert old.latest_step is new.latest_step
-    import repro.resilience as rz
-
-    assert rz.CheckpointManager is CheckpointManager
-
-
 # ----------------------------------------------------- kill/resume identity
 
 
