@@ -46,9 +46,9 @@ class Supergraph:
 #   * "merge" (default) — two-level scheme: (1) the persistent state stays
 #     sorted by (a, b); (2) the incoming chunk is deduped *locally*, one
 #     sort of only the C chunk entries; (3) the deduped run merges into the
-#     state by the ``kernels/merge`` sorted-merge-and-combine kernel, whose
-#     ranks are binary searches because both runs are already sorted —
-#     O(C log C + cap + C) per chunk.
+#     state by the ``kernels/merge`` sorted-merge-and-combine kernel, which
+#     ranks the two already-sorted runs against each other (a banded walk
+#     on TPU, binary searches elsewhere) — O(C log C + cap + C) per chunk.
 #   * "lexsort" — the original baseline: concatenate state + chunk, one
 #     full lexsort, segment-sum back into capacity — O((cap + C)·
 #     log(cap + C)) per chunk.

@@ -4,10 +4,14 @@ Merges two (a, b)-sorted deduplicated superedge runs — the persistent
 aggregation state [cap] and a locally deduped chunk [C] — into one sorted
 deduplicated run of the state's capacity, summing the weights of pairs
 present in both. It exploits both inputs already being sorted: output
-ranks come from vectorized binary searches (``jnp.searchsorted``) and the
-rows land with two scatters, so a chunk update costs O(cap + C) moves plus
+ranks come from binary searches (``jnp.searchsorted``) and the rows land
+with two scatters, so a chunk update costs O(cap + C) moves plus
 O((cap + C)·log) comparisons — never the lexsort baseline's full
-O((cap + C)·log(cap + C)) re-sort of state + chunk.
+O((cap + C)·log(cap + C)) re-sort of state + chunk. This is the CPU path
+and the tests' oracle. On a TPU each level of a binary search is a round
+of random gathers, which made these ranks the aggregation's largest cost;
+the Pallas path ranks the two runs against each other by a banded walk
+instead (``sorted_merge.merge_ranks``), with bit-identical results.
 
 Pairs compare as packed uint32 keys ``a·s_cap + b``: valid pairs satisfy
 ``a < b < s_cap ≤ 2¹⁶`` so the packing is collision-free and

@@ -14,8 +14,12 @@ from repro.kernels.repulsion import ops as rep_ops
 from repro.kernels.segment.seg_matmul import segment_sum_pallas
 from repro.kernels.segment.ref import segment_sum_ref
 from repro.kernels.segment import ops as seg_ops
-from repro.kernels.merge.ref import merge_combine_ref
-from repro.kernels.merge.sorted_merge import merge_combine_pallas
+from repro.kernels.merge.ref import merge_combine_ref, merge_positions
+from repro.kernels.merge.sorted_merge import (
+    merge_combine_pallas,
+    merge_positions_pallas,
+    merge_ranks,
+)
 from repro.kernels.merge import ops as merge_ops
 from repro.obs.metrics import REGISTRY
 from repro.kernels.raster.ref import (
@@ -259,6 +263,8 @@ def _assert_merge_outputs_equal(got, want, label=""):
     (256, 64, 200, 64, 128, 64),  # tn = 2·blk: a band of three blocks
     (128, 64, 128, 60, 32, 16),  # state full to a block boundary, overflows
     (128, 64, 100, 0, 64, 32),  # chunk all sentinel
+    (512, 16, 300, 16, 64, 32),  # cap ≫ C: the chunk fits one rank block
+    (32, 256, 20, 200, 32, 16),  # C ≫ cap: the state fits one rank block
 ])
 def test_merge_kernel_vs_ref(cap, c, ks, kc, tn, blk):
     rng = np.random.default_rng(cap + c)
@@ -360,6 +366,129 @@ def test_merge_grid_is_banded():
     assert 0 < steps <= 2 * band * tiles
     assert dense == tiles * (cap + c) // blk
     assert steps * 4 < dense
+
+
+_SENT = 0xFFFFFFFF
+
+
+def _key_run(keys, size: int):
+    """Sorted uint32 keys, sentinel-padded to ``size``."""
+    keys = np.sort(np.asarray(keys, np.uint64))
+    out = np.full(size, _SENT, np.uint32)
+    out[: len(keys)] = keys
+    return out
+
+
+def _unique_keys(rng, k: int, lo: int = 0, hi: int = _SENT):
+    """k distinct keys in [lo, hi), sorted."""
+    keys = np.unique(rng.integers(lo, hi, 2 * k + 8, dtype=np.uint64))
+    return rng.choice(keys, k, replace=False)
+
+
+def _rank_case(case: str, rng):
+    """(queries, table, weights or None) for one ``merge_ranks`` case."""
+    if case == "random":
+        return (_key_run(_unique_keys(rng, 50), 64),
+                _key_run(rng.integers(0, 1000, 70), 80), None)
+    if case == "all_sentinel":
+        return _key_run([], 40), _key_run([], 24), None
+    if case == "queries_below_table":
+        return (_key_run(_unique_keys(rng, 30, 0, 1000), 32),
+                _key_run(_unique_keys(rng, 60, 5000, 9000), 64), None)
+    if case == "queries_above_table":
+        return (_key_run(_unique_keys(rng, 30, 5000, 9000), 32),
+                _key_run(_unique_keys(rng, 60, 0, 1000), 64), None)
+    if case == "queries_in_one_table_block":
+        table = _unique_keys(rng, 60, 0, 10_000)
+        inside = np.arange(table[20], table[22] + 1)
+        return (_key_run(rng.choice(inside, min(30, inside.size), replace=False), 32),
+                _key_run(table, 64), None)
+    if case == "table_in_one_query_block":
+        queries = _unique_keys(rng, 60, 0, 10_000)
+        inside = np.arange(queries[20], queries[22] + 1)
+        return (_key_run(queries, 64),
+                _key_run(rng.choice(inside, 30), 32), None)
+    if case == "table_much_larger":  # cap ≫ C
+        return (_key_run(_unique_keys(rng, 6), 8),
+                _key_run(_unique_keys(rng, 300), 320), None)
+    if case == "queries_much_larger":  # C ≫ cap
+        return (_key_run(_unique_keys(rng, 300), 320),
+                _key_run(_unique_keys(rng, 6), 8), None)
+    if case == "keys_above_2_31":
+        hi = _unique_keys(rng, 80, 1 << 31, (1 << 31) + 400)
+        return (_key_run(hi[:40], 48),
+                _key_run(np.concatenate([hi[30:], _unique_keys(rng, 10, 0, 1 << 31)]), 64),
+                None)
+    # weighted: a 0/1 weight on the table, which holds the sentinel too.
+    table = _key_run(_unique_keys(rng, 50, 0, 2000), 64)
+    return (_key_run(_unique_keys(rng, 40, 0, 2000), 48), table,
+            rng.integers(0, 2, table.size).astype(bool))
+
+
+@pytest.mark.parametrize("bq,bt", [(8, 8), (16, 8), (8, 32)])
+@pytest.mark.parametrize("case", [
+    "random", "all_sentinel", "queries_below_table", "queries_above_table",
+    "queries_in_one_table_block", "table_in_one_query_block",
+    "table_much_larger", "queries_much_larger", "keys_above_2_31", "weighted",
+])
+def test_merge_ranks_vs_searchsorted(case, bq, bt):
+    """The rank walk (interpret mode) against ``np.searchsorted`` (side
+    left, weighted by a prefix sum of ``wt``) and ``table == query``
+    membership, for every query, sentinels included."""
+    q, t, wt = _rank_case(case, np.random.default_rng(17))
+    cnt, eq = merge_ranks(
+        jnp.asarray(q), jnp.asarray(t), None if wt is None else jnp.asarray(wt),
+        bq=bq, bt=bt, interpret=True,
+    )
+    ins = np.searchsorted(t, q, side="left")
+    if wt is not None:
+        ins = np.concatenate([[0], np.cumsum(wt)])[ins]
+    np.testing.assert_array_equal(np.asarray(cnt), ins, err_msg=case)
+    np.testing.assert_array_equal(np.asarray(eq), np.isin(q, t), err_msg=case)
+
+
+@pytest.mark.parametrize("cap,c,live,c_live,dups,blk", [
+    (256, 128, 200, 100, 40, 16),
+    (300, 70, 290, 70, 70, 32),  # every chunk key held, cap not block-aligned
+    (64, 512, 50, 400, 10, 32),  # C ≫ cap
+    (1024, 32, 700, 20, 5, 64),  # cap ≫ C
+    (128, 64, 0, 60, 0, 16),  # empty state
+])
+def test_merge_positions_pallas_vs_ref(cap, c, live, c_live, dups, blk):
+    """The two rank walks give ``ref.merge_positions``' three outputs bit
+    for bit on sorted unique runs, ``dups`` chunk keys held by the state."""
+    rng = np.random.default_rng(cap + c)
+    state = _unique_keys(rng, live)
+    fresh = np.setdiff1d(_unique_keys(rng, 2 * c_live), state)
+    chunk = np.concatenate([
+        rng.choice(state, dups, replace=False) if dups else [],
+        rng.choice(fresh, c_live - dups, replace=False),
+    ])
+    sk, ck = jnp.asarray(_key_run(state, cap)), jnp.asarray(_key_run(chunk, c))
+    got = merge_positions_pallas(sk, ck, blk=blk, interpret=True)
+    _assert_merge_outputs_equal(got, merge_positions(sk, ck))
+
+
+def test_merge_rank_grid_is_banded():
+    """Each rank pass walks at most q_blocks + t_blocks steps, where a
+    dense (q_blocks × t_blocks) grid would take their product."""
+    rng = np.random.default_rng(13)
+    s_cap, cap, c, blk = 64, 384, 112, 16  # shapes no other test traces
+    sa, sb, sw = _sorted_run(_rand_pairs(rng, 300, s_cap), cap, s_cap)
+    ca, cb, cw = _sorted_run(_rand_pairs(rng, 70, s_cap), c, s_cap)
+    for name in ("merge.rank_grid_steps", "merge.rank_search_steps"):
+        REGISTRY.gauge(name).set(-1)
+    got = merge_combine_pallas(
+        sa, sb, sw, ca, cb, cw, s_cap, tn=32, blk=blk, interpret=True
+    )
+    _assert_merge_outputs_equal(got, merge_combine_ref(sa, sb, sw, ca, cb, cw, s_cap))
+    state_blocks, chunk_blocks = cap // blk, c // blk
+    steps = REGISTRY.value("merge.rank_grid_steps")
+    assert 0 < steps <= 2 * (state_blocks + chunk_blocks)
+    assert steps < 2 * state_blocks * chunk_blocks  # a dense grid per pass
+    assert REGISTRY.value("merge.rank_search_steps") == (
+        c * int(np.ceil(np.log2(cap + 1))) + cap * int(np.ceil(np.log2(c + 1)))
+    )
 
 
 def test_merge_ops_wrapper():

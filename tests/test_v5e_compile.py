@@ -37,6 +37,7 @@ LIVEJOURNAL_CMS_COLS = 34_681  # default_cms_cols(34.68M edges)
 MAX_SUPER_EDGES = 262_144  # default_config's max_super_edges cap
 SMOKE_SUPER_EDGES = 1 << 20  # chip_smoke.py's capacity for this graph
 WEBGOOGLE_SUPER_EDGES = 1 << 21  # the web-Google benchmark's capacity
+SKITTER_SUPER_EDGES = 1 << 22  # the as-Skitter benchmark's capacity
 CHUNK = 1 << 20  # edges per streamed chunk in the chip smoke run
 N_GROUPS = len(PALETTE)  # render accumulation channels
 SKITTER_SMALL_DISKS = 1 << 16  # as-Skitter's small-disk splat, padded to 2^k
@@ -136,10 +137,12 @@ def test_grid_near_field_compiles(one_chip):
     (MAX_SUPER_EDGES, 1 << 14),
     (SMOKE_SUPER_EDGES, CHUNK),
     (WEBGOOGLE_SUPER_EDGES, CHUNK),
+    (SKITTER_SUPER_EDGES, CHUNK),
 ])
 def test_merge_combine_compiles(one_chip, cap, c):
-    """Superedge state (default, BerkStan's and web-Google's capacity) +
-    one deduped chunk run."""
+    """Superedge state (default, BerkStan's, web-Google's and as-Skitter's
+    capacity) + one deduped chunk run; both the rank walk and the scatter
+    are Pallas kernels in the program."""
     compiled = merge_combine_pallas.lower(
         _sds((cap,), "int32", one_chip),
         _sds((cap,), "int32", one_chip),
@@ -150,6 +153,8 @@ def test_merge_combine_compiles(one_chip, cap, c):
         s_cap=1 << 16,
     ).compile()
     _assert_kernel(compiled)
+    text = compiled.as_text()
+    assert "merge_ranks" in text and "merge_scatter_combine" in text
 
 
 @pytest.mark.parametrize("n,side", [
